@@ -231,18 +231,9 @@ class ShapeWarmer:
         # count lands on an unwarmed step would stall a slot third on
         # the ~2-minute trace+lower cost. The warmer is a background
         # daemon; the duplicate-free set below is len(menu) entries.
-        from lighthouse_tpu.ops.backend import (
-            M_BUCKET_SHIFTS,
-            _m_bucket_for,
-            _next_pow2,
-        )
+        from lighthouse_tpu.ops.backend import bm_m_menu
 
-        m_low = _next_pow2(max(1, n_devices or 1))
-        menu = {
-            max(_m_bucket_for(n_bucket, max(1, n_bucket >> shift)), m_low)
-            for shift in M_BUCKET_SHIFTS
-        }
-        for m_bucket in sorted(menu):
+        for m_bucket in bm_m_menu(n_bucket, n_devices or 1):
             u = jnp.zeros((2, 2, lb.L, m_bucket), dtype=lb.DTYPE)
             row_mask = jnp.zeros((m_bucket,), dtype=bool)
             args = (u, inv_idx % m_bucket, row_mask, pk_proj, sig_proj,

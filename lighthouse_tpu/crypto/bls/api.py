@@ -329,16 +329,29 @@ def get_backend() -> str:
     return _active_backend
 
 
-def verify_signature_sets(sets: Sequence[SignatureSet], backend: Optional[str] = None) -> bool:
-    """The north-star entry point (BASELINE.json): batch-verify signature sets
-    on the active backend. On False, callers re-verify individually to find
-    the poisoned item (reference batch.rs:123-134 fallback semantics)."""
-    name = backend or _active_backend
+# Backends whose bisection verifies sub-batches through a verifier built
+# from the ROOT batch (name -> make(sets) -> verify(sub_sets) -> bool): the
+# device backend pins every sub-batch to the root's compiled shapes.
+_BISECT_VERIFIERS = {}
+
+
+def register_bisect_verifier(name: str, make) -> None:
+    _BISECT_VERIFIERS[name] = make
+
+
+def _backend_fn(name: str):
     if name == "tpu" and "tpu" not in _BACKENDS:
         from lighthouse_tpu.ops import backend as _tpu_backend  # noqa: F401
     if name == "cpu" and "cpu" not in _BACKENDS:
         from . import cpu_backend as _cpu_backend  # noqa: F401
-    return _BACKENDS[name](list(sets))
+    return _BACKENDS[name]
+
+
+def verify_signature_sets(sets: Sequence[SignatureSet], backend: Optional[str] = None) -> bool:
+    """The north-star entry point (BASELINE.json): batch-verify signature sets
+    on the active backend. On False, callers re-verify individually to find
+    the poisoned item (reference batch.rs:123-134 fallback semantics)."""
+    return _backend_fn(backend or _active_backend)(list(sets))
 
 
 def find_invalid_sets(
@@ -348,16 +361,24 @@ def find_invalid_sets(
     and each failing half recurses — ~2·log2(n)·k batch calls for k culprits
     instead of the reference's n per-item re-verifications
     (attestation_verification/batch.rs:123-134; SURVEY.md §7.3 item 4 says
-    do this on-device to avoid host round-trips — halving keeps every call
-    a power-of-two bucket the backend has already compiled).
+    do this on-device to avoid host round-trips). A backend with a
+    registered bisect verifier (the device backend) stages every sub-batch
+    at the root batch's shapes, so isolation never compiles a new one.
 
     Returns the indices of invalid sets (empty when the whole batch
     verifies)."""
     sets = list(sets)
     out: list = []
+    name = backend or _active_backend
+    _backend_fn(name)  # lazy backends register their bisect verifier
+    if name in _BISECT_VERIFIERS and sets:
+        verify = _BISECT_VERIFIERS[name](sets)
+    else:
+        def verify(sub):
+            return verify_signature_sets(sub, backend=backend)
 
     def recurse(lo: int, hi: int) -> None:
-        if verify_signature_sets(sets[lo:hi], backend=backend):
+        if verify(sets[lo:hi]):
             return
         if hi - lo == 1:
             out.append(lo)

@@ -46,13 +46,15 @@ def _env_facts() -> Dict[str, Any]:
 
 
 def make(probe: str, params: Optional[Dict[str, Any]] = None,
-         **extra) -> Dict[str, Any]:
+         env_facts: bool = True, **extra) -> Dict[str, Any]:
+    """`env_facts=False` keeps the call off jax: a parent process that
+    spawns children needing the chip must not initialize a backend."""
     report: Dict[str, Any] = {
         "schema": SCHEMA,
         "probe": probe,
         "ok": True,
         "started_unix": round(time.time(), 3),
-        "env": _env_facts(),
+        "env": _env_facts() if env_facts else {},
         "params": dict(params or {}),
         "results": {},
     }

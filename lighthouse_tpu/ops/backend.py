@@ -64,27 +64,25 @@ def _warm_dispatch(stage_id: str, fallback):
     (serving/aot.py): a restarted process serves bundle-covered shapes
     from deserialized exports instead of re-tracing the ~60k-op graphs.
     No bundle (the default) = one None check per call, then `fallback`.
-    Guarded: the serving layer is optional and must never break the
-    engine."""
+    Only a missing serving layer is tolerated: a broken bundle or seam
+    must surface, not silently fall back."""
     try:
         from lighthouse_tpu.serving import aot
-
-        return aot.stage_dispatch("major", stage_id, fallback)
-    except Exception:
+    except ImportError:
         return fallback
+    return aot.stage_dispatch("major", stage_id, fallback)
 
 
 def _traced(stage: str, fn, **static_args):
     """Observability stage wrapper (observability/stages.traced): a
     no-op attribute check unless tracing/stage-timing is active, in
     which case the stage blocks until ready and reports its true wall
-    time. Guarded for the same reason as _warm_dispatch."""
+    time. Tolerates only a missing module, as _warm_dispatch does."""
     try:
         from lighthouse_tpu.observability import stages as _obs_stages
-
-        return _obs_stages.traced("major", stage, fn, **static_args)
-    except Exception:
+    except ImportError:
         return fn
+    return _obs_stages.traced("major", stage, fn, **static_args)
 
 
 # ---------------------------------------------------------------------------
@@ -264,26 +262,49 @@ def verify_signature_sets_tpu(
     return bool(_verify_tpu_impl(sets, sharded))
 
 
-def _verify_tpu_impl(sets, sharded):
+def cpu_fallback_max() -> int:
+    """Small-batch host fallback (SURVEY §7.3 item 3 / VERDICT r2 #2): a
+    handful of gossip-latency sets should not pay device dispatch +
+    bucket padding; the native C++ verifier answers in ~2-7 ms/set.
+    Batches of at most this many sets answer natively.
+    LIGHTHOUSE_TPU_CPU_FALLBACK_MAX=0 disables (the device-path tests pin
+    it to 0 so small shapes still exercise the JAX kernels)."""
+    try:
+        return int(os.environ.get("LIGHTHOUSE_TPU_CPU_FALLBACK_MAX", "16"))
+    except ValueError:
+        return 16
+
+
+def _host_rejects(s) -> bool:
+    """The oracle/blst rejects decided before any device work."""
+    return not s.signing_keys or s.signature.point is None
+
+
+def _buckets(sets, sharded, floors=(1, 1, 1)):
+    """(sharded, n_devices, n_bucket, k_bucket, m_floor) for a batch;
+    `floors` (n, k, m) pins buckets from below (find_invalid_sets pins a
+    poisoned batch's sub-batches to the root's shapes)."""
+    if sharded is None:
+        sharded = len(jax.devices()) > 1
+    n_devices = len(jax.devices()) if sharded else None
+    floor_n = n_devices or 1
+    n_bucket = _next_pow2(len(sets), floor=max(floor_n, floors[0]))
+    k_bucket = _next_pow2(max(len(s.signing_keys) for s in sets),
+                          floor=floors[1])
+    m_floor = max(_next_pow2(floor_n), floors[2])
+    if _layout() == "bm":
+        m_floor = max(m_floor, BM_M_FLOOR)
+    return bool(sharded), n_devices, n_bucket, k_bucket, m_floor
+
+
+def _verify_tpu_impl(sets, sharded, floors=(1, 1, 1)):
     sets = list(sets)
     if not sets:
         return False
-    for s in sets:
-        if not s.signing_keys:
-            return False
-        if s.signature.point is None:
-            return False
+    if any(_host_rejects(s) for s in sets):
+        return False
 
-    # Small-batch host fallback (SURVEY §7.3 item 3 / VERDICT r2 #2): a
-    # handful of gossip-latency sets should not pay device dispatch +
-    # bucket padding; the native C++ verifier answers in ~2-7 ms/set.
-    # LIGHTHOUSE_TPU_CPU_FALLBACK_MAX=0 disables (the device-path tests
-    # pin it to 0 so small shapes still exercise the JAX kernels).
-    try:
-        fb_max = int(os.environ.get("LIGHTHOUSE_TPU_CPU_FALLBACK_MAX", "16"))
-    except ValueError:
-        fb_max = 16
-    if len(sets) <= fb_max:
+    if len(sets) <= cpu_fallback_max():
         try:
             from lighthouse_tpu.crypto.bls import cpu_backend
             return cpu_backend.verify_signature_sets_cpu(sets)
@@ -291,12 +312,8 @@ def _verify_tpu_impl(sets, sharded):
             pass  # no native toolchain: stay on the device path
 
     n = len(sets)
-    k_max = max(len(s.signing_keys) for s in sets)
-    if sharded is None:
-        sharded = len(jax.devices()) > 1
-    floor_n = len(jax.devices()) if sharded else 1
-    n_bucket = _next_pow2(n, floor=max(1, floor_n))
-    k_bucket = _next_pow2(k_max)
+    sharded, n_devices, n_bucket, k_bucket, m_floor = _buckets(
+        sets, sharded, floors)
 
     # Engine layout: "bm" stages batch-minor tensors (the round-5 tile-
     # utilization re-layout, ops/bm/). Since round 6 the SHARDED path runs
@@ -304,10 +321,8 @@ def _verify_tpu_impl(sets, sharded):
     # (parallel.mesh.minor_sharding) instead of falling back to the
     # batch-major engine and forfeiting the ~2.4-2.9x layout win.
     if _layout() == "bm":
-        return _verify_bm_impl(
-            sets, n, n_bucket, k_bucket, sharded=bool(sharded),
-            n_devices=len(jax.devices()) if sharded else None,
-        )
+        return _verify_bm_impl(sets, n, n_bucket, k_bucket, m_floor,
+                               n_devices)
 
     # --- stage tensors (host ints -> device limbs) ------------------------
     # Hash-cons identical messages BEFORE the host SHA and the device h2c
@@ -323,9 +338,7 @@ def _verify_tpu_impl(sets, sharded):
     # committee count here too. Padding rows map through h2c but are
     # never gathered (inv_idx only points at real rows). The sharded
     # floor keeps every shard non-empty.
-    m_bucket = max(
-        _m_bucket_for(n_bucket, len(uniq)), _next_pow2(max(1, floor_n))
-    )
+    m_bucket = max(_m_bucket_for(n_bucket, len(uniq)), m_floor)
     u = np.zeros((m_bucket, 2, 2, lb.L), dtype=lb.NP_DTYPE)
     u_real = h2c.hash_to_field_device(list(uniq.keys()))
     u[: len(uniq)] = np.asarray(u_real)
@@ -391,6 +404,25 @@ def _layout() -> str:
 # {64, 256, 1024, 4096, 16384}, covering the 64-committee firehose shape
 # exactly.
 M_BUCKET_SHIFTS = (8, 6, 4, 2, 0)
+
+# The batch-minor engine's m bucket never drops below 64: its distinct-
+# message axis is the vreg LANE axis (128 wide), so m = 1..64 costs the
+# same vregs in h2c and the m + 1 Miller rows still fit one lane tile —
+# while each distinct m is its own h2g2 and Miller-stage compile (about a
+# minute each for one v5e, compiled on this repo's CPU sandbox). A gossip
+# slot's batches (1-18 committees each) then share one executable of each
+# instead of six.
+BM_M_FLOOR = 64
+
+
+def bm_m_menu(n_bucket: int, n_devices: int = 1) -> list:
+    """Every m bucket BM staging can produce for an n bucket (the warmer
+    and the AOT bundle walk exactly this list)."""
+    floor = max(_next_pow2(max(1, n_devices)), BM_M_FLOOR)
+    return sorted({
+        max(_m_bucket_for(n_bucket, max(1, n_bucket >> s)), floor)
+        for s in M_BUCKET_SHIFTS
+    })
 
 
 def max_n_bucket() -> int:
@@ -487,17 +519,15 @@ def stage_bm(sets, n, n_bucket, k_bucket, scalars=None, m_floor: int = 1):
     return args, m_bucket
 
 
-def _verify_bm_impl(sets, n, n_bucket, k_bucket, sharded: bool = False,
+def _verify_bm_impl(sets, n, n_bucket, k_bucket, m_floor: int,
                     n_devices: Optional[int] = None):
     """Run the batch-minor core (ops/bm/backend.py) on a staged batch.
-    `sharded` places every staged tensor with its trailing (minor) batch
-    axis sharded over the mesh and compiles the mesh-constrained core."""
+    A mesh size (`n_devices`) places every staged tensor with its trailing
+    (minor) batch axis sharded over the mesh and compiles the
+    mesh-constrained core."""
     from .bm import backend as bmb
 
-    m_floor = 1
-    if sharded:
-        n_devices = n_devices or len(jax.devices())
-        m_floor = _next_pow2(max(1, n_devices))
+    sharded = n_devices is not None
     args, m_bucket = stage_bm(sets, n, n_bucket, k_bucket, m_floor=m_floor)
     if sharded:
         from lighthouse_tpu.parallel import mesh as pm
@@ -509,6 +539,23 @@ def _verify_bm_impl(sets, n, n_bucket, k_bucket, sharded: bool = False,
     return core(*args)
 
 
+def pinned_verifier(sets, sharded: Optional[bool] = None):
+    """find_invalid_sets' sub-batch verifier: each sub-batch is staged at
+    the ROOT batch's (n, k, m) buckets, so isolating a poisoned set runs
+    only the shapes the root batch compiled. Halving an all-distinct
+    batch would otherwise compile three new stages per level (minutes
+    each on the chip)."""
+    good = [s for s in sets if not _host_rejects(s)]
+    if not good:
+        return verify_signature_sets_tpu
+    sharded, _, n_bucket, k_bucket, m_floor = _buckets(good, sharded)
+    n_uniq = len({bytes(s.message) for s in good})
+    floors = (n_bucket, k_bucket,
+              max(_m_bucket_for(n_bucket, n_uniq), m_floor))
+    return lambda sub: bool(_verify_tpu_impl(sub, sharded, floors))
+
+
 # Register with the API seam (mirrors define_mod! backend instantiation,
 # crypto/bls/src/lib.rs:99-140).
 _api.register_backend("tpu", verify_signature_sets_tpu)
+_api.register_bisect_verifier("tpu", pinned_verifier)

@@ -2,7 +2,8 @@
 the batch-minor layout, with same-message PAIR COMBINING.
 
 Pipeline (hash-consed h2c -> aggregation/validity/weighting + segmented
-same-message combine -> product-of-pairings over DISTINCT messages):
+same-message combine -> Miller loops over DISTINCT messages -> one final
+exponentiation, a stage of its own shared by every bucket shape):
 
 The blst batch equation prod_i e([r_i] A_i, H(m_i)) * e(-g1, S) == 1 is
 evaluated after grouping by message: bilinearity gives
@@ -44,6 +45,7 @@ from . import curves as cv
 from . import h2c
 from . import limbs as lb
 from . import pairing as pr
+from . import tower as tw
 
 # -g1 generator, batch-minor projective with a minor batch axis of 1.
 _NEG_G1 = cv.g1_from_affine([(_oc.G1_GEN[0], _P - _oc.G1_GEN[1])])
@@ -217,22 +219,33 @@ def _make_prepare(m_bucket: int, prep_chunk: int = 0):
     return _prepare_pairs
 
 
-def _pairing_check(p_proj, h_unique, s_proj, row_mask, sets_valid):
-    """Product of pairings over the m distinct messages + the signature
-    pair (all-projective, one final exponentiation)."""
+def _miller_product(p_proj, h_unique, s_proj, row_mask):
+    """Miller loops over the m distinct messages + the signature pair
+    (all-projective), masked and multiplied into one Fp12."""
     q_proj = jnp.concatenate([h_unique, s_proj], axis=-1)
     mask = jnp.concatenate([row_mask, jnp.ones((1,), dtype=bool)])
-    pairing_ok = pr.multi_pairing_check(p_proj, q_proj, mask)
-    return jnp.logical_and(pairing_ok, sets_valid)
+    return pr.miller_product_proj(p_proj, q_proj, mask)
 
 
-# Stage 1/3 jits are MODULE-LEVEL singletons: their graphs depend only on
-# the distinct-message bucket m (stage 1 maps u, stage 3 pairs m+1 rows),
-# so sharing one jit wrapper across every (n, k) core lets jax's own
-# executable cache dedupe them — the warm grid compiles each m once
-# instead of once per bucket shape.
+def _final_check(prod, sets_valid):
+    """The one final exponentiation == 1, ANDed with per-set validity.
+    Its input is a single Fp12 whatever the bucket, so one executable
+    serves every (n, k, m): split from the Miller stage because the
+    exponentiation is most of the pairing's compile (~2.5 of ~3 minutes
+    for one v5e), which every new m used to pay again."""
+    return jnp.logical_and(
+        tw.fp12_is_one(pr.final_exponentiation(prod))[..., 0], sets_valid)
+
+
+# Stage 1/3/4 jits are MODULE-LEVEL singletons: their graphs depend only
+# on the distinct-message bucket m (stage 1 maps u, stage 3 pairs m+1
+# rows) or on nothing (stage 4), so sharing one jit wrapper across every
+# (n, k) core lets jax's own executable cache dedupe them — the warm grid
+# compiles each m once instead of once per bucket shape.
+FP12_SHAPE = tw.FP12_ONE.shape            # stage 4's input: one Fp12
 _stage1_jit = jax.jit(_h2g2)
-_stage3_jit = jax.jit(_pairing_check)
+_stage3_jit = jax.jit(_miller_product)
+_stage4_jit = jax.jit(_final_check)
 
 
 @lru_cache(maxsize=None)
@@ -246,10 +259,9 @@ def _warm_dispatch(stage_id: str, fallback):
     width because the scan structure isn't visible in the avals)."""
     try:
         from lighthouse_tpu.serving import aot
-
-        return aot.stage_dispatch("bm", stage_id, fallback)
-    except Exception:
+    except ImportError:
         return fallback
+    return aot.stage_dispatch("bm", stage_id, fallback)
 
 
 def _traced(stage: str, fn, **static_args):
@@ -257,10 +269,9 @@ def _traced(stage: str, fn, **static_args):
     label "bm"."""
     try:
         from lighthouse_tpu.observability import stages as _obs_stages
-
-        return _obs_stages.traced("bm", stage, fn, **static_args)
-    except Exception:
+    except ImportError:
         return fn
+    return _obs_stages.traced("bm", stage, fn, **static_args)
 
 
 def jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
@@ -295,6 +306,7 @@ def _jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
         stage2 = _warm_dispatch(f"prepare:c{prep_chunk}",
                                 _prepare_jit(m_bucket, prep_chunk))
         stage3 = _warm_dispatch("pairing", _stage3_jit)
+        stage4 = _warm_dispatch("final_exp", _stage4_jit)
     else:
         from lighthouse_tpu.parallel import mesh as pm
 
@@ -317,11 +329,13 @@ def _jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
         # major sharded path does.
         stage1 = jax.jit(constrained(_h2g2))
         stage2 = jax.jit(constrained(_make_prepare(m_bucket, prep_chunk)))
-        stage3 = jax.jit(_pairing_check)
+        stage3 = jax.jit(_miller_product)
+        stage4 = jax.jit(_final_check)
 
     stage1 = _traced("h2g2", stage1, **shape_args)
     stage2 = _traced("prepare", stage2, **shape_args)
     stage3 = _traced("pairing", stage3, **shape_args)
+    stage4 = _traced("final_exp", stage4, **shape_args)
 
     def core(u, inv_idx, row_mask, pk_proj, sig_proj, sig_checked,
              set_mask, scalars):
@@ -329,7 +343,8 @@ def _jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
         p_proj, s_proj, sets_valid = stage2(
             pk_proj, sig_proj, sig_checked, set_mask, scalars, inv_idx
         )
-        return stage3(p_proj, h_unique, s_proj, row_mask, sets_valid)
+        return stage4(stage3(p_proj, h_unique, s_proj, row_mask),
+                      sets_valid)
 
-    core.stages = (stage1, stage2, stage3)
+    core.stages = (stage1, stage2, stage3, stage4)
     return core

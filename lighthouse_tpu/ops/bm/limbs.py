@@ -104,11 +104,7 @@ import os as _os
 # (DotThunk "BF16 x BF16 = F32" — the same limitation behind the
 # per-prime dots in limbs._inv_gammas), so CPU uses the einsum lowering.
 def _default_mm():
-    import jax as _jax
-    try:
-        return "einsum" if _jax.default_backend() == "cpu" else "matmul"
-    except Exception:
-        return "einsum"
+    return "einsum" if jax.default_backend() == "cpu" else "matmul"
 
 
 # Resolved LAZILY on the first _matmul_const call (ADVICE r5 #1): reading

@@ -44,12 +44,6 @@ from jax.experimental import pallas as pl
 
 from . import limbs as lb
 
-# jax >= 0.4.31 removed the jax.enable_x64 alias; the context manager
-# lives in jax.experimental on every version this package supports.
-_enable_x64 = getattr(jax, "enable_x64", None)
-if _enable_x64 is None:
-    from jax.experimental import enable_x64 as _enable_x64
-
 # --------------------------------------------------------------------------
 # Mode selection
 # --------------------------------------------------------------------------
@@ -374,7 +368,7 @@ def squeeze_fwd(x, plan):
     # x64 must be OFF while tracing the kernel: the package enables
     # jax_enable_x64 globally (ops/__init__.py) and Mosaic cannot
     # legalize the 64-bit index/literal types it injects.
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = _fwd_call(rows_p, blk, plan.n_p, _interpret())(
             xf, off, v, p_row, inv_row)
     return out[:rows].reshape(shape + (plan.n_p, _N))
@@ -390,7 +384,7 @@ def inv_out(c, plan, with_offset: bool):
     cf, rows_p = _pad_rows(cf, blk)
     consts = _inv_consts(plan.n_p, with_offset)
     args = [cf] + [a for a in consts if a is not None]
-    with _enable_x64(False):        # see squeeze_fwd
+    with jax.enable_x64(False):        # see squeeze_fwd
         out = _inv_call(
             rows_p, blk, plan.n_p, with_offset, _interpret())(*args)
     return out[:rows].reshape(shape + (_L,))
@@ -701,6 +695,6 @@ def fp12_op(kind: str, a, b=None, line=None):
         lf, _ = _pad_rows(lf, blk)
         args.append(lf)
     args += list(_k3_args(lb.plan4().n_p))
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = _k3_fp12_call(rows_p, kind, _interpret())(*args)
     return out[:rows].reshape(shape + (2, 3, 2, _L))

@@ -167,6 +167,12 @@ def _backend_m_menu(n_bucket: int) -> List[int]:
     return sorted({max(1, n_bucket >> s) for s in M_BUCKET_SHIFTS})
 
 
+def _bm_m_menu(n_bucket: int) -> List[int]:
+    from lighthouse_tpu.ops.backend import bm_m_menu
+
+    return bm_m_menu(n_bucket)
+
+
 def _major_stages(n: int, k: int, m: int):
     import jax
     import jax.numpy as jnp
@@ -205,15 +211,17 @@ def _bm_stages(n: int, k: int, m: int):
          (S((k, 3, lb.L, n), D), S((3, 2, lb.L, n), D),
           S((n,), jnp.bool_), S((n,), jnp.bool_), S((n,), jnp.uint64),
           S((n,), jnp.int32))),
-        ("pairing", bmb._pairing_check,
+        ("pairing", bmb._miller_product,
          (S((3, lb.L, m + 1), D), S((3, 2, lb.L, m), D),
-          S((3, 2, lb.L, 1), D), S((m,), jnp.bool_), S((), jnp.bool_))),
+          S((3, 2, lb.L, 1), D), S((m,), jnp.bool_))),
+        ("final_exp", bmb._final_check,
+         (S(bmb.FP12_SHAPE, D), S((), jnp.bool_))),
     ]
 
 
 _LAYOUTS: Dict[str, LayoutSpec] = {
     "major": LayoutSpec("major", _major_stages, _backend_m_menu),
-    "bm": LayoutSpec("bm", _bm_stages, _backend_m_menu),
+    "bm": LayoutSpec("bm", _bm_stages, _bm_m_menu),
 }
 
 

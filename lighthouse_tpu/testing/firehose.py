@@ -57,13 +57,25 @@ def graft_validators(chain, n_extra: int, pubkey: bytes = None) -> None:
         state.inactivity_scores.append(0)
 
 
-def build_firehose_chain(n_extra: int, n_real: int = 32):
+def mainnet_capella_spec():
+    """Mainnet preset (32 slots, up to 64 committees per slot) with every
+    fork through Capella active at genesis, as the harness's genesis
+    builder expects: the committee shape of a mainnet gossip slot."""
+    from lighthouse_tpu.types.spec import ChainSpec
+
+    return ChainSpec(altair_fork_epoch=0, bellatrix_fork_epoch=0,
+                     capella_fork_epoch=0, deneb_fork_epoch=None)
+
+
+def build_firehose_chain(n_extra: int, n_real: int = 32, spec=None):
     """Harness chain with `n_extra` grafted validators sharing validator
     0's pubkey (signatures by key 0 are honestly verifiable for every
-    registry index via the pubkey-cache shortcut)."""
+    registry index via the pubkey-cache shortcut). `spec` defaults to the
+    harness's minimal spec."""
     from lighthouse_tpu.testing.harness import BeaconChainHarness
 
-    harness = BeaconChainHarness(n_validators=n_real, bls_backend="tpu")
+    harness = BeaconChainHarness(n_validators=n_real, bls_backend="tpu",
+                                 spec=spec)
     chain = harness.chain
     pk0_bytes = bytes(chain.head.state.validators[0].pubkey)
     graft_validators(chain, n_extra, pubkey=pk0_bytes)
@@ -108,31 +120,38 @@ def make_signed_single_bit_attestations(harness, slot: int,
     return atts
 
 
-def run_firehose(harness, attestations, max_bucket: int,
-                 warm=(8,)) -> dict:
+def run_firehose(harness, attestations, policy=None) -> dict:
     """Feed attestations through the batch former into
-    chain.process_attestation_batch; returns per-batch latencies and
-    import counts."""
+    chain.process_attestation_batch; returns per-batch latencies, import
+    counts, the batch sizes the former chose and the input positions of
+    rejected attestations. `policy` defaults to the node's own
+    AdaptiveBatchPolicy() (client/builder.py)."""
     chain = harness.chain
-    proc = BeaconProcessor(
-        batch_policy=AdaptiveBatchPolicy(max_bucket=max_bucket, warm=warm)
-    )
+    proc = BeaconProcessor(batch_policy=policy or AdaptiveBatchPolicy())
+    pos = {id(att): i for i, att in enumerate(attestations)}
     batch_lat: List[float] = []
-    imported = [0]
+    batch_sizes: List[int] = []
+    batch_of: dict = {}
+    rejected: List[int] = []
 
     def process_batch(batch):
         t0 = time.monotonic()
         results = chain.process_attestation_batch(batch)
         batch_lat.append(time.monotonic() - t0)
-        imported[0] += sum(
-            1 for r in results if not isinstance(r, Exception)
-        )
+        batch_sizes.append(len(batch))
+        for att, r in zip(batch, results):
+            batch_of[pos[id(att)]] = len(batch_sizes) - 1
+            if isinstance(r, Exception):
+                rejected.append(pos[id(att)])
 
     def process_one(att):
         t0 = time.monotonic()
+        batch_sizes.append(1)
+        batch_of[pos[id(att)]] = len(batch_sizes) - 1
         try:
             chain.process_attestation(att)
-            imported[0] += 1
+        except Exception:
+            rejected.append(pos[id(att)])
         finally:
             batch_lat.append(time.monotonic() - t0)
 
@@ -149,7 +168,10 @@ def run_firehose(harness, attestations, max_bucket: int,
     batch_lat.sort()
     return {
         "n_atts": len(attestations),
-        "imported": imported[0],
+        "imported": len(attestations) - len(rejected),
+        "rejected": sorted(rejected),
+        "batch_of": batch_of,
+        "batch_sizes": batch_sizes,
         "batches": proc.stats.batches,
         "batched_items": proc.stats.batched_items,
         "total_s": total,

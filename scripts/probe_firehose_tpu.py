@@ -25,6 +25,7 @@ def main():
 
     import jax
 
+    from lighthouse_tpu.beacon_processor import AdaptiveBatchPolicy
     from lighthouse_tpu.testing.firehose import (
         build_firehose_chain,
         make_signed_single_bit_attestations,
@@ -59,14 +60,14 @@ def main():
     # process: the persistent cache skips re-optimization, but tracing +
     # lowering a ~60k-op stage still costs ~minutes on this 1-core host;
     # the in-client ShapeWarmer hides this behind startup).
-    warm = (max_bucket,)
+    def policy():
+        return AdaptiveBatchPolicy(max_bucket=max_bucket, warm=(max_bucket,))
+
     n3 = len(atts) // 3
     for lo, hi in ((0, n3), (n3, 2 * n3)):
-        stats_warm = run_firehose(harness, atts[lo:hi],
-                                  max_bucket=max_bucket, warm=warm)
+        stats_warm = run_firehose(harness, atts[lo:hi], policy())
         print(f"warm pass: {stats_warm}", file=sys.stderr)
-    stats = run_firehose(harness, atts[2 * n3:], max_bucket=max_bucket,
-                         warm=warm)
+    stats = run_firehose(harness, atts[2 * n3:], policy())
 
     third = spec.seconds_per_slot / 3.0
     per_att = stats["total_s"] / max(1, stats["imported"])

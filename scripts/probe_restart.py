@@ -6,10 +6,10 @@ verify a full-size batch NOW. Cold, each bucket shape pays trace + lower
 (minutes per shape even small); with an AOT warm bundle (serving/aot.py)
 the stages deserialize in seconds. The probe:
 
-  1. ensures a bundle exists for the probe shape (exporting it once if
-     needed — that one-time cost is printed as the measured cold
-     evidence; `--cold` additionally runs a true cold consumer against
-     an empty compilation cache);
+  1. ensures a bundle exists for the probe shape, in a child process
+     (exporting it once if needed — that one-time cost is printed as the
+     measured cold evidence; `--cold` additionally runs a true cold
+     consumer against an empty compilation cache);
   2. spawns a FRESH consumer process (the "restarted node") pointed at
      the bundle, which warms the shape, then drives a mixed
      attestation + sync-signature workload through the continuous
@@ -17,7 +17,9 @@ the stages deserialize in seconds. The probe:
   3. prints warm start-to-first-batch next to the cold number, plus the
      consumer's router decisions and scheduler deadline hits/misses.
 
-CPU-runnable:
+The parent never touches JAX: a chip belongs to one process at a time,
+so the export and each consumer run in their own child, one after
+another. It runs on whatever platform JAX picks; on the CPU:
 
     JAX_PLATFORMS=cpu python scripts/probe_restart.py --bundle /tmp/wb
 
@@ -42,11 +44,14 @@ sys.path.insert(0, REPO)
 # ---------------------------------------------------------------------------
 
 
-def consumer(n: int, k: int) -> int:
+def consumer(n: int, k: int, bundle: str) -> int:
     """Measure start-to-first-full-size-verified-batch in THIS process.
     Emits one probe-report JSON line (observability/report.py schema) on
-    stdout; everything else goes to stderr."""
+    stdout; everything else goes to stderr. `bundle` "" = no bundle."""
     os.environ["LIGHTHOUSE_TPU_CPU_FALLBACK_MAX"] = "0"  # measure device
+    from lighthouse_tpu.serving import aot
+
+    os.environ[aot.ENV_VAR] = bundle
 
     from lighthouse_tpu.observability import report as obs_report
 
@@ -137,27 +142,58 @@ def consumer(n: int, k: int) -> int:
     return 0 if ok else 1
 
 
+def export(bundle_dir: str, n: int, k: int) -> int:
+    """Ensure the bundle covers (n, k), exporting it if needed; report
+    the export seconds (measured now, or recorded in the manifest)."""
+    from lighthouse_tpu.observability import report as obs_report
+    from lighthouse_tpu.serving import aot
+
+    rep = obs_report.make("probe_restart.export",
+                          params={"n": n, "k": k, "bundle": bundle_dir})
+    shape_prefix = f"{aot._current_layout()}|n={n}|k={k}|"
+    bundle = aot.open_bundle(bundle_dir)
+    if bundle is None or not any(
+            key.startswith(shape_prefix) for key in bundle.entries):
+        print(f"exporting ({n}, {k}) -> {bundle_dir} "
+              "(one-time; this is the cold cost being front-loaded)",
+              file=sys.stderr)
+        report = aot.make_bundle(bundle_dir, [(n, k)],
+                                 progress=lambda s: print(s, file=sys.stderr))
+        for e in report.errors:
+            print(f"  ERROR {e}", file=sys.stderr)
+        obs_report.emit(obs_report.finish(
+            rep, ok=not report.errors,
+            results={"export_secs": report.export_secs}))
+        return 1 if report.errors else 0
+    # Measured at production time, recorded in the manifest.
+    secs = sum(sum(e.get("export_secs", []))
+               for key, e in bundle.entries.items()
+               if key.startswith(shape_prefix))
+    obs_report.emit(obs_report.finish(rep, ok=True,
+                                      results={"export_secs": secs}))
+    return 0
+
+
 # ---------------------------------------------------------------------------
-# Parent: ensure bundle, spawn consumers, compare
+# Parent: stays off JAX; runs the export and the consumers one by one
 # ---------------------------------------------------------------------------
 
 
-def _spawn_consumer(n, k, env_extra):
+def _spawn(mode, n, k, bundle, env_extra=None):
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env.update(env_extra)
+    env.update(env_extra or {})
     proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--consumer", f"--n={n}", f"--k={k}"],
+        [sys.executable, os.path.abspath(__file__), f"--{mode}",
+         f"--n={n}", f"--k={k}", f"--bundle={bundle}"],
         env=env, cwd=REPO, capture_output=True, text=True)
     sys.stderr.write(proc.stderr)
     from lighthouse_tpu.observability import report as obs_report
 
     docs = obs_report.parse_lines(proc.stdout)
-    if docs:
+    if docs and proc.returncode == 0:
         return docs[-1]["results"]
     raise RuntimeError(
-        f"consumer emitted no probe report (rc={proc.returncode}):\n"
+        f"{mode} child failed (rc={proc.returncode}):\n"
         f"{proc.stdout[-2000:]}")
 
 
@@ -172,45 +208,21 @@ def main(argv=None):
                     help="also run a TRUE cold consumer (no bundle, empty "
                     "compilation cache) — adds minutes of XLA compile")
     ap.add_argument("--consumer", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument("--export", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
     if args.consumer:
-        return consumer(args.n, args.k)
+        return consumer(args.n, args.k, args.bundle)
+    if args.export:
+        return export(args.bundle, args.n, args.k)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from lighthouse_tpu.serving import aot
-
-    # 1. Ensure the bundle covers the probe shape; the export cost is the
-    #    measured cold evidence (it IS the trace+lower a cold node pays).
-    layout = aot._current_layout()
-    shape_prefix = f"{layout}|n={args.n}|k={args.k}|"
-    bundle = aot.open_bundle(args.bundle)
-    have = bundle is not None and any(
-        key.startswith(shape_prefix) for key in bundle.entries)
-    export_secs = None
-    if not have:
-        print(f"exporting ({args.n}, {args.k}) -> {args.bundle} "
-              "(one-time; this is the cold cost being front-loaded)")
-        report = aot.make_bundle(args.bundle, [(args.n, args.k)],
-                                 progress=print)
-        if report.errors:
-            for e in report.errors:
-                print(f"  ERROR {e}")
-            return 1
-        export_secs = report.export_secs
-    if export_secs is None:
-        # Measured at production time, recorded in the manifest.
-        bundle = aot.open_bundle(args.bundle)
-        export_secs = sum(
-            sum(e.get("export_secs", []))
-            for key, e in bundle.entries.items()
-            if key.startswith(shape_prefix))
+    # 1. Bundle export (child), its cost the measured cold evidence.
+    export_secs = _spawn("export", args.n, args.k,
+                         args.bundle)["export_secs"]
 
     # 2. Fresh consumer process, bundle active.
     print("\n--- warm consumer (fresh process, bundle active) ---")
-    warm = _spawn_consumer(args.n, args.k, {
-        aot.ENV_VAR: args.bundle,
-    })
+    warm = _spawn("consumer", args.n, args.k, args.bundle)
 
     cold = None
     if args.cold:
@@ -218,10 +230,8 @@ def main(argv=None):
         import tempfile
 
         with tempfile.TemporaryDirectory() as empty_cache:
-            cold = _spawn_consumer(args.n, args.k, {
-                aot.ENV_VAR: "",
-                "LIGHTHOUSE_TPU_JAX_CACHE": empty_cache,
-            })
+            cold = _spawn("consumer", args.n, args.k, "",
+                          {"JAX_COMPILATION_CACHE_DIR": empty_cache})
 
     # 3. Report.
     print("\n=== restart-mid-slot probe ===")
@@ -251,7 +261,7 @@ def main(argv=None):
 
     rep = obs_report.make("probe_restart", params={
         "n": args.n, "k": args.k, "bundle": args.bundle,
-        "cold": bool(args.cold)})
+        "cold": bool(args.cold)}, env_facts=False)
     obs_report.emit(obs_report.finish(rep, ok=ok, results={
         "warm": warm, "cold": cold,
         "export_secs": round(export_secs, 2)}))

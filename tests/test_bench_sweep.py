@@ -55,19 +55,29 @@ def test_all_distinct_row_selection():
     assert bench._all_distinct_row([]) == {}
 
 
-def test_default_sweep_caps_n_on_cpu(monkeypatch):
-    """The default shape list drops the 8192 rungs on the CPU tier (a
-    cold 8192 compile is minutes of XLA for a rung CPU never runs),
-    keeps them on accelerators, and honors the explicit override."""
+def test_default_sweep_cap_override(monkeypatch):
+    """The default shape list is the full menu (8192 rungs included) and
+    LIGHTHOUSE_TPU_BENCH_SWEEP_MAX_N caps it."""
     monkeypatch.delenv("LIGHTHOUSE_TPU_BENCH_SWEEP_MAX_N", raising=False)
-    cpu = bench._default_sweep_shapes(cpu_only=True)
-    assert max(n for n, _, _ in cpu) == 4096
-    acc = bench._default_sweep_shapes(cpu_only=False)
-    assert (8192, 4, 8192) in acc and (8192, 4, 64) in acc
-    assert cpu == [s for s in acc if s[0] <= 4096]
-
-    monkeypatch.setenv("LIGHTHOUSE_TPU_BENCH_SWEEP_MAX_N", "8192")
-    assert (8192, 4, 64) in bench._default_sweep_shapes(cpu_only=True)
+    full = bench._default_sweep_shapes()
+    assert (8192, 4, 8192) in full and (8192, 4, 64) in full
     monkeypatch.setenv("LIGHTHOUSE_TPU_BENCH_SWEEP_MAX_N", "1024")
-    acc_capped = bench._default_sweep_shapes(cpu_only=False)
-    assert max(n for n, _, _ in acc_capped) == 1024
+    capped = bench._default_sweep_shapes()
+    assert max(n for n, _, _ in capped) == 1024
+    assert capped == [s for s in full if s[0] <= 1024]
+
+
+def test_bench_refuses_without_tpu():
+    """bench.py never falls back to the CPU: no TPU, no result line,
+    non-zero exit."""
+    import subprocess
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    proc = subprocess.run(
+        [sys.executable, str(root / "bench.py")], cwd=root,
+        capture_output=True, text=True, timeout=300,
+        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr

@@ -60,3 +60,23 @@ def test_multiple_poisons_found():
 def test_all_bad():
     out = bls.find_invalid_sets(_make_sets(3, bad_indices={0, 1, 2}))
     assert out == [0, 1, 2]
+
+
+def test_device_bisection_stays_on_the_root_shape(monkeypatch):
+    """The device backend's bisection stages every sub-batch at the root
+    batch's buckets, so isolating a culprit compiles no new shape."""
+    from lighthouse_tpu.crypto.bls import cpu_backend
+    from lighthouse_tpu.ops import backend as be
+
+    seen = []
+
+    def fake_impl(sets, sharded, floors=(1, 1, 1)):
+        seen.append((len(sets), floors))
+        return cpu_backend.verify_signature_sets_cpu(sets)
+
+    monkeypatch.setattr(be, "_verify_tpu_impl", fake_impl)
+    sets = _make_sets(8, bad_indices={5})
+    assert bls.find_invalid_sets(sets, backend="tpu") == [5]
+    assert [n for n, _ in seen] == [8, 4, 4, 2, 1, 1, 2]
+    _, _, n_bucket, k_bucket, m_floor = be._buckets(sets, None)
+    assert {f for _, f in seen} == {(n_bucket, k_bucket, m_floor)}

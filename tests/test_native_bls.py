@@ -133,3 +133,37 @@ def test_small_batch_routing(monkeypatch):
 def test_cpu_backend_registered_via_api():
     sets = [_set_for(_keypair(40), b"\x07" * 32)]
     assert api.verify_signature_sets(sets, backend="cpu") is True
+
+
+def test_native_build_is_keyed_by_source_flags_and_host(tmp_path,
+                                                         monkeypatch):
+    """A build whose key (source, flags, host CPU flags) does not match is
+    never loaded: another host's -march=native artifact is rebuilt, not
+    reused (it could die with SIGILL here)."""
+    from lighthouse_tpu import native
+
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "probe.cpp").write_text(
+        'extern "C" int probe_answer() { return 42; }\n')
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_BUILD", str(tmp_path / "build"))
+    monkeypatch.setattr(native, "_cache", {})
+
+    assert native.load("probe").probe_answer() == 42
+    here = sorted(os.listdir(tmp_path / "build"))
+    assert here == [f"libprobe-{native.build_key(str(src / 'probe.cpp'))}.so"]
+
+    # Same source and flags on a host with other CPU features: new key,
+    # new build; the first artifact is left alone, not loaded.
+    monkeypatch.setattr(native, "_cpu_flags", lambda: "another-host")
+    monkeypatch.setattr(native, "_cache", {})
+    assert native.load("probe").probe_answer() == 42
+    assert len(os.listdir(tmp_path / "build")) == 2
+
+    # An edited source is a new key, so it is rebuilt: the key, not a
+    # timestamp, decides.
+    (src / "probe.cpp").write_text(
+        'extern "C" int probe_answer() { return 7; }\n')
+    monkeypatch.setattr(native, "_cache", {})
+    assert native.load("probe").probe_answer() == 7

@@ -304,7 +304,7 @@ def test_engine_cores_expose_traced_stages():
     core = be._jitted_core(4, 1, False)
     assert len(core.stages) == 3
     core_bm = bmb.jitted_core(4, 1, 4)
-    assert len(core_bm.stages) == 3
+    assert len(core_bm.stages) == 4     # + the shared final exponentiation
 
 
 # ---------------------------------------------------------------------------
@@ -577,9 +577,13 @@ def test_roofline_table_from_synthetic_trace(tmp_path, capsys):
                      "report_roofline.py"))
     rr = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(rr)
-    assert rr.main(["--from-trace", str(path)]) == 0
+    assert rr.main(["--from-trace", str(path),
+                    "--device-kind", "TPU v5 lite"]) == 0
     out = capsys.readouterr().out
     assert "h2c" in out and "prep(+combine)" in out and "pairing" in out
     assert "roofline:" in out
     # 1024 sets / 0.1s total = 10240 sigs/s in the TOTAL row.
     assert "10,240" in out
+    # No default peak: a kind outside the published table is an error.
+    with pytest.raises(SystemExit):
+        rr.main(["--from-trace", str(path), "--device-kind", "cpu"])

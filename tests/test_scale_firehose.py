@@ -25,6 +25,7 @@ import time
 
 import pytest
 
+from lighthouse_tpu.beacon_processor import AdaptiveBatchPolicy
 from lighthouse_tpu.testing.firehose import (
     build_firehose_chain,
     graft_validators as _graft_validators,
@@ -159,7 +160,8 @@ def test_firehose_500k_verification_on():
     )
     assert len(atts) >= 24
 
-    stats = run_firehose(harness, atts, max_bucket=8, warm=(8,))
+    stats = run_firehose(
+        harness, atts, AdaptiveBatchPolicy(max_bucket=8, warm=(8,)))
     assert stats["imported"] == len(atts), stats
     assert stats["batches"] >= 2
 
