@@ -216,15 +216,13 @@ def _host_peak_rss_gib() -> float:
     return round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20, 2)
 
 
-def _stage_runs() -> int:
-    """Runs of the engine's pairing stage: one per batch that reached the
-    device engine (BM on a chip, batch-major on the CPU rehearsal; stage
-    timing must be on, as main() forces it)."""
-    from lighthouse_tpu.observability import stages
+def _device_batches() -> int:
+    """Batches the device engine answered so far:
+    `bls_batches_total{route="device"}` (BM on a chip, batch-major on the
+    CPU rehearsal)."""
+    from lighthouse_tpu.ops import backend as be
 
-    hist = stages.stage_seconds()
-    return sum(hist.get_count(engine=e, stage="pairing")
-               for e in ("bm", "major"))
+    return int(be.batches_total().get("device"))
 
 
 # ---------------------------------------------------------------------------
@@ -303,13 +301,13 @@ def phase_b(prepared, n_poison: int = N_POISON_ATTS) -> dict:
     out = {}
 
     t0 = time.perf_counter()
-    runs0 = _stage_runs()
+    runs0 = _device_batches()
     st = run_firehose(h_ok, atts)
     check(st["imported"] == len(atts) and not st["rejected"],
           f"valid slot: imported {st['imported']}/{len(atts)}")
     device_batches = sum(1 for b in st["batch_sizes"]
                          if b > cpu_fallback_max())
-    check(_stage_runs() - runs0 >= device_batches,
+    check(_device_batches() - runs0 >= device_batches,
           "valid slot: batches did not run on the device engine")
     out["valid"] = {"n_atts": len(atts), "imported": st["imported"],
                     "batch_sizes": st["batch_sizes"],
@@ -384,11 +382,11 @@ def phase_c(sets) -> dict:
     bad = poisoned_copy(sets, bad_idx)
     out = {"n": n, "k": len(sets[0].signing_keys), "poisoned_index": bad_idx}
     for name, batch, want in (("valid", sets, True), ("poisoned", bad, False)):
-        runs0 = _stage_runs()
+        runs0 = _device_batches()
         t0 = time.perf_counter()
         dev = api.verify_signature_sets(batch, backend="tpu")
         t_dev = time.perf_counter() - t0
-        check(_stage_runs() - runs0 >= (n > cpu_fallback_max()),
+        check(_device_batches() - runs0 >= (n > cpu_fallback_max()),
               f"phase C {name}: the batch did not run on the device engine")
         t0 = time.perf_counter()
         native = cpu_backend.verify_signature_sets_cpu(batch)
@@ -421,8 +419,8 @@ def sharded_jobs(n: int, k: int, m: int, n_devices: int):
     mesh = pm.get_mesh(n_devices)
     s1, s2, _ = _stage_avals(n, k, m, lambda nd: pm.minor_sharding(mesh, nd))
     core = bmb.jitted_core(n, k, m, sharded=True, n_devices=n_devices)
-    return [(("sharded-h2g2", m), core.stages[0].__wrapped__, s1),
-            (("sharded-prepare", n, k, m), core.stages[1].__wrapped__, s2)]
+    return [(("sharded-h2g2", m), core.stages[0], s1),
+            (("sharded-prepare", n, k, m), core.stages[1], s2)]
 
 
 def verify_sharded(sets, n_devices: int):
@@ -476,10 +474,9 @@ def main(argv=None) -> int:
 
     t_start = time.perf_counter()
     from lighthouse_tpu.common import metrics as m
-    from lighthouse_tpu.observability import compile_events, stages
+    from lighthouse_tpu.observability import compile_events
 
     compile_events.install()
-    stages.force_timing(True)   # per-stage device runs feed the checks
 
     # Compile every stage shape in threads while the main thread builds
     # the phases' data (pure Python); Phase B starts once its own shapes
@@ -545,7 +542,7 @@ def main(argv=None) -> int:
     diag(compiles=_compiles(), compile_ahead_jobs=len(compiler.labels()),
          cache_dir=cache_dir, cache_files=len(os.listdir(cache_dir))
          if cache_dir and os.path.isdir(cache_dir) else 0,
-         pairing_stage_runs=_stage_runs(),
+         device_batches=_device_batches(),
          router_fallback_retried=retried,
          peak_bytes_in_use=mem.get("peak_bytes_in_use"),
          host_peak_rss_gib=_host_peak_rss_gib(),

@@ -19,6 +19,7 @@ from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
 from lighthouse_tpu.crypto.bls import api as bls
+from lighthouse_tpu.observability import trace
 from lighthouse_tpu.state_transition import signature_sets as sigsets
 
 
@@ -137,9 +138,12 @@ def verify_unaggregated_attestation(
     chain, attestation, subnet_id: Optional[int] = None
 ) -> VerifiedUnaggregatedAttestation:
     """Single-item path (verify_attestation_signature :1088-1116)."""
-    indexed = verify_unaggregated_checks(chain, attestation, subnet_id)
-    iatt = _indexed_from_committee(chain.types, attestation, indexed.committee)
-    sset = _unagg_signature_set(chain, iatt)
+    with trace.span("att.checks", cat="attestation", n=1):
+        indexed = verify_unaggregated_checks(chain, attestation, subnet_id)
+    with trace.span("att.set_build", cat="attestation", n=1):
+        iatt = _indexed_from_committee(chain.types, attestation,
+                                       indexed.committee)
+        sset = _unagg_signature_set(chain, iatt)
     if not bls.verify_signature_sets([sset], backend=chain.bls_backend):
         raise AttestationError("InvalidSignature")
     return VerifiedUnaggregatedAttestation(
@@ -172,14 +176,24 @@ def batch_verify_unaggregated_attestations(
     given, aligned with the inputs) names the gossip peer each item came
     from so a poisoned signature is charged to its sender."""
     results: List[object] = [None] * len(attestations)
+    # Two passes in item order: the gossip checks (and their observed_*
+    # side effects), then the indexed attestations and signature sets of
+    # the items that passed them (which the checks have already indexed
+    # once, so the second pass raises no AttestationError).
+    checked = []  # (idx, IndexedUnaggregated)
+    with trace.span("att.checks", cat="attestation", n=len(attestations)):
+        for i, (att, subnet_id) in enumerate(attestations):
+            try:
+                checked.append(
+                    (i, verify_unaggregated_checks(chain, att, subnet_id)))
+            except AttestationError as e:
+                results[i] = e
     staged = []  # (idx, IndexedUnaggregated, indexed_att, sig_set)
-    for i, (att, subnet_id) in enumerate(attestations):
-        try:
-            ind = verify_unaggregated_checks(chain, att, subnet_id)
-            iatt = _indexed_from_committee(chain.types, att, ind.committee)
+    with trace.span("att.set_build", cat="attestation", n=len(checked)):
+        for i, ind in checked:
+            iatt = _indexed_from_committee(chain.types, attestations[i][0],
+                                           ind.committee)
             staged.append((i, ind, iatt, _unagg_signature_set(chain, iatt)))
-        except AttestationError as e:
-            results[i] = e
 
     if staged:
         sets = [s[3] for s in staged]

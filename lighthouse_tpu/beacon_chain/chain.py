@@ -35,6 +35,7 @@ from lighthouse_tpu.common.slot_clock import ManualSlotClock, SlotClock
 from lighthouse_tpu.execution_layer.execution_layer import normalize_lvh
 from lighthouse_tpu.fork_choice.fork_choice import CheckpointSnapshot, ForkChoice
 from lighthouse_tpu.fork_choice.proto_array import ExecutionStatus
+from lighthouse_tpu.observability import trace
 from lighthouse_tpu.state_transition import helpers as h
 from lighthouse_tpu.state_transition import slot_processing as sp
 from lighthouse_tpu.store.hot_cold import HotColdDB
@@ -414,18 +415,22 @@ class BeaconChain:
         verified = att_ver.verify_unaggregated_attestation(
             self, attestation, subnet_id
         )
-        self.apply_attestation_to_fork_choice(verified.indexed_attestation)
-        self._feed_slasher(verified.indexed_attestation)
-        if self.op_pool is not None:
-            self.op_pool.insert_attestation(attestation, verified.indexed_attestation)
+        with trace.span("att.import", cat="attestation", n=1):
+            self.apply_attestation_to_fork_choice(verified.indexed_attestation)
+            self._feed_slasher(verified.indexed_attestation)
+            if self.op_pool is not None:
+                self.op_pool.insert_attestation(
+                    attestation, verified.indexed_attestation)
         return verified
 
     def process_attestation_batch(self, attestations, origins=None):
         results = att_ver.batch_verify_unaggregated_attestations(
             self, [(a, None) for a in attestations], origins=origins
         )
-        for r in results:
-            if isinstance(r, att_ver.VerifiedUnaggregatedAttestation):
+        verified = [r for r in results
+                    if isinstance(r, att_ver.VerifiedUnaggregatedAttestation)]
+        with trace.span("att.import", cat="attestation", n=len(verified)):
+            for r in verified:
                 self.apply_attestation_to_fork_choice(r.indexed_attestation)
                 self._feed_slasher(r.indexed_attestation)
                 if self.op_pool is not None:

@@ -25,6 +25,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional
 
+from lighthouse_tpu.observability import trace
+
 logger = logging.getLogger(__name__)
 
 # Queue capacities (lib.rs:83-196 envelope).
@@ -232,11 +234,15 @@ class BeaconProcessor:
         return None
 
     def step(self) -> bool:
-        """One manager iteration. Returns False when idle."""
+        """One manager iteration. Returns False when idle. The work runs
+        in a `bp.batch` span (a batch: `kind`, `n`, and `depth`, the
+        items left in its queue at the pop) or a `bp.item` span."""
         with self._lock:
             work = self._pop_next()
-        if work is None:
-            return False
+            if work is None:
+                return False
+            kind = work[0].kind
+            depth = len(self.queues[kind])
         if len(work) > 1:
             self.stats.batches += 1
             self.stats.batched_items += len(work)
@@ -247,18 +253,22 @@ class BeaconProcessor:
                 # drained per-item must not raise the growth cap to an
                 # uncompiled shape (mid-slot cold-compile hazard).
                 self.batch_policy.note_ran(len(work))
-            if batch_fn is not None:
-                batch_fn([w.item for w in work])
-            else:
-                for w in work:
-                    if w.process_individual:
-                        w.process_individual(w.item)
+            with trace.span("bp.batch", cat="processor", kind=kind,
+                            n=len(work), depth=depth):
+                if batch_fn is not None:
+                    batch_fn([w.item for w in work])
+                else:
+                    for w in work:
+                        if w.process_individual:
+                            w.process_individual(w.item)
         else:
             w = work[0]
             self.stats.processed += 1
             if w.process_individual:
-                w.process_individual(w.item)
-        self._m_processed.labels(work[0].kind).inc(len(work))
+                with trace.span("bp.item", cat="processor", kind=kind,
+                                depth=depth):
+                    w.process_individual(w.item)
+        self._m_processed.labels(kind).inc(len(work))
         if len(work) == 1:
             return True
         self.stats.processed += len(work)

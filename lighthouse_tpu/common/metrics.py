@@ -280,8 +280,8 @@ class Histogram:
 
 class LabeledHistogram(_Family):
     """A histogram family: per-label-set bucket/sum/count series under
-    one header (the stage-timer `engine_stage_seconds{engine=,stage=}`
-    shape). Children are full Histograms sharing the family buckets."""
+    one header (a `stage_seconds{engine=,stage=}` shape). Children are
+    full Histograms sharing the family buckets."""
 
     kind = "histogram"
 

@@ -29,6 +29,8 @@ import secrets
 from dataclasses import dataclass
 from typing import Iterable, List, Optional, Sequence
 
+from lighthouse_tpu.observability import trace
+
 from . import curves as c
 from . import fields as f
 from . import hash_to_curve as h2c
@@ -366,9 +368,11 @@ def find_invalid_sets(
     at the root batch's shapes, so isolation never compiles a new one.
 
     Returns the indices of invalid sets (empty when the whole batch
-    verifies)."""
+    verifies). The `bls.bisect` span carries `n`, and on exit `calls`
+    (verifications made, the whole batch's included) and `bad`."""
     sets = list(sets)
     out: list = []
+    calls = 0
     name = backend or _active_backend
     _backend_fn(name)  # lazy backends register their bisect verifier
     if name in _BISECT_VERIFIERS and sets:
@@ -378,6 +382,8 @@ def find_invalid_sets(
             return verify_signature_sets(sub, backend=backend)
 
     def recurse(lo: int, hi: int) -> None:
+        nonlocal calls
+        calls += 1
         if verify(sets[lo:hi]):
             return
         if hi - lo == 1:
@@ -387,6 +393,8 @@ def find_invalid_sets(
         recurse(lo, mid)
         recurse(mid, hi)
 
-    if sets:
-        recurse(0, len(sets))
+    with trace.span("bls.bisect", cat="bls", n=len(sets)) as sp:
+        if sets:
+            recurse(0, len(sets))
+        sp.set(calls=calls, bad=len(out))
     return out

@@ -1,16 +1,12 @@
-"""Observability spine: span tracing, stage timers, compile-event
-accounting, and the shared probe-report schema (ROADMAP Open item 2's
-measurement layer).
+"""Observability spine: span tracing, compile-event accounting, and the
+shared probe-report schema (ROADMAP Open item 2's measurement layer).
 
-Four cooperating pieces:
+The pieces:
 
-  * `trace`          — process-global span tracer with Chrome
-                       trace-event JSON export; off by default, one
-                       attribute read when disabled.
-  * `stages`         — `traced(engine, stage, fn)` wrappers the engine
-                       builders apply to every stage callable; active
-                       tracing adds the `block_until_ready` seam and
-                       feeds `engine_stage_seconds{engine,stage}`.
+  * `trace`          — the one span API: every span is a
+                       `jax.profiler.TraceAnnotation` (on the device
+                       trace's clock under a profiler session) and, when
+                       enabled, an event in a Chrome trace-event buffer.
   * `compile_events` — executable-provenance counters (first compile vs
                        persistent-cache hit vs warm-bundle hit) plus
                        jax-internal monitoring hooks.
@@ -25,15 +21,14 @@ never be the thing that takes the batch path down.
 
 Submodules import lazily (PEP 562): `ops.backend` and `serving.aot`
 consult this package from inside builders, and an eager import of
-`stages` (which imports `common.metrics`) from those seams would cycle
-through `lighthouse_tpu` package init.
+`compile_events` (which imports `common.metrics`) from those seams would
+cycle through `lighthouse_tpu` package init.
 """
 
-_SUBMODULES = ("trace", "stages", "compile_events", "report",
-               "timeseries", "slo")
+_SUBMODULES = ("trace", "compile_events", "report", "timeseries", "slo")
 
 __all__ = [
-    "trace", "stages", "compile_events", "report", "timeseries", "slo",
+    "trace", "compile_events", "report", "timeseries", "slo",
     "Tracer", "TRACER", "span", "instant", "enable", "disable",
     "TimeSeries", "SloEngine", "Objective", "serving_objectives",
 ]

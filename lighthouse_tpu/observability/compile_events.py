@@ -24,8 +24,8 @@ from typing import Optional
 from lighthouse_tpu.common import metrics as m
 from lighthouse_tpu.observability import trace
 
-# The event vocabulary (scripts/report_roofline.py and the docs key off
-# these exact strings):
+# The event vocabulary (the docs and the benchmark's compile count key
+# off these exact strings):
 #   first_compile         jax persistent-cache miss -> full XLA compile
 #   persistent_cache_hit  jax persistent-cache hit  -> deserialize only
 #   warm_bundle_hit       serving/aot bundle loaded (no jax work at all)

@@ -1,19 +1,24 @@
-"""Span tracer with Chrome trace-event JSON export.
+"""The package's one span API, with two sinks.
 
-The engine's unit of time is an XLA dispatch, not a function call, so
-profilers that sample the Python stack see nothing: the interesting
-boundaries are the three stage dispatches, the compile/cache events
-around them, and the serving-layer lifecycle that feeds them. This
-module records exactly those as spans and exports the standard Chrome
-trace-event format (`chrome://tracing` / Perfetto both open it):
-complete events (`ph:"X"`, microsecond `ts`/`dur`), instants (`ph:"i"`)
-and counter series (`ph:"C"`).
+`span(name, **args)` opens a `jax.profiler.TraceAnnotation(name, **args)`
+around the `with` body. With no profiler session that is one TraceMe
+check; under `jax.profiler.start_trace` the span lands on the host line
+of its thread in the `.xplane.pb`, on the same clock as the device's
+`XLA Modules` events, with its args as event stats. Where jax cannot be
+imported the annotation is skipped.
 
-Tracing is OFF by default and the disabled path is one attribute read —
-the engines stay async-pipelined (no `block_until_ready` seams) unless a
-trace is being taken. Enable programmatically (`trace.enable()`) or via
-`LIGHTHOUSE_TPU_TRACE=1`; setting it to a path (`/tmp/run.trace.json`)
-also installs an atexit export to that path.
+The second sink is the operator's trace when no profiler runs: an
+in-memory buffer exported as Chrome trace-event JSON (`chrome://tracing`
+and Perfetto both open it): complete events (`ph:"X"`, microsecond
+`ts`/`dur`) and instants (`ph:"i"`). It is OFF by default and the
+disabled path is one attribute read. Enable it programmatically
+(`trace.enable()`) or via `LIGHTHOUSE_TPU_TRACE=1`; setting it to a path
+(`/tmp/run.trace.json`) also installs an atexit export to that path.
+
+Span names on the node's path take one of three prefixes: `bp.` (the
+beacon processor), `att.` (attestation verification and import) and
+`bls.` (the BLS backends). Args are small scalars; one span per phase
+of a batch, never one per attestation or per set.
 """
 
 from __future__ import annotations
@@ -72,6 +77,23 @@ class Tracer:
 
     # ----------------------------------------------------------- recording
 
+    def _open(self, name: str):
+        """Start a complete event on this thread: (t0_us, depth)."""
+        stack = self._depth_stack()
+        stack.append(name)
+        return self._now_us(), len(stack)
+
+    def _close(self, name: str, cat: str, t0: float, depth: int,
+               args: Dict[str, Any]) -> None:
+        t1 = self._now_us()
+        self._depth_stack().pop()
+        self._push({
+            "name": name, "cat": cat, "ph": "X",
+            "ts": t0, "dur": max(t1 - t0, 0.0),
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "args": {"depth": depth, **args},
+        })
+
     @contextmanager
     def span(self, name: str, cat: str = "engine", **args):
         """Record a complete event around the `with` body. Nesting depth
@@ -80,24 +102,11 @@ class Tracer:
         if not self.enabled:
             yield None
             return
-        stack = self._depth_stack()
-        stack.append(name)
-        depth = len(stack)
-        t0 = self._now_us()
+        t0, depth = self._open(name)
         try:
             yield self
         finally:
-            t1 = self._now_us()
-            stack.pop()
-            ev_args = {"depth": depth}
-            if args:
-                ev_args.update(args)
-            self._push({
-                "name": name, "cat": cat, "ph": "X",
-                "ts": t0, "dur": max(t1 - t0, 0.0),
-                "pid": os.getpid(), "tid": threading.get_ident(),
-                "args": ev_args,
-            })
+            self._close(name, cat, t0, depth, args)
 
     def instant(self, name: str, cat: str = "engine", **args) -> None:
         if not self.enabled:
@@ -107,19 +116,6 @@ class Tracer:
             "ts": self._now_us(),
             "pid": os.getpid(), "tid": threading.get_ident(),
             "args": dict(args),
-        })
-
-    def counter_series(self, name: str, cat: str = "engine",
-                       **values) -> None:
-        """A `ph:"C"` sample — one point per keyword on the named series
-        (queue depths over time, in-flight batches...)."""
-        if not self.enabled:
-            return
-        self._push({
-            "name": name, "cat": cat, "ph": "C",
-            "ts": self._now_us(),
-            "pid": os.getpid(), "tid": threading.get_ident(),
-            "args": dict(values),
         })
 
     # ------------------------------------------------------------- export
@@ -154,6 +150,53 @@ class Tracer:
 # records here, so one enable() captures engine + serving + processor.
 TRACER = Tracer()
 
+_annotation = None   # jax.profiler.TraceAnnotation, False without jax
+
+
+def _annotation_cls():
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class Span:
+    """A span of the `span()` API. `set(**args)` adds args known only at
+    its end (sub-batch calls made, culprits found); both sinks get them."""
+
+    __slots__ = ("name", "cat", "args", "late", "_ann", "_t0", "_depth")
+
+    def __init__(self, name: str, cat: str, args: Dict[str, Any]):
+        self.name, self.cat, self.args = name, cat, args
+        self.late: Dict[str, Any] = {}
+        self._ann = None
+        self._t0 = None
+
+    def set(self, **args) -> None:
+        self.late.update(args)
+
+    def __enter__(self) -> "Span":
+        cls = _annotation_cls()
+        if cls:
+            self._ann = cls(self.name, **self.args)
+            self._ann.__enter__()
+        if TRACER.enabled:
+            self._t0, self._depth = TRACER._open(self.name)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            if self.late:
+                self._ann.set_metadata(**self.late)
+            self._ann.__exit__(None, None, None)
+        if self._t0 is not None:
+            TRACER._close(self.name, self.cat, self._t0, self._depth,
+                          {**self.args, **self.late})
+
 
 def enabled() -> bool:
     return TRACER.enabled
@@ -168,16 +211,14 @@ def disable() -> None:
     TRACER.disable()
 
 
-def span(name: str, cat: str = "engine", **args):
-    return TRACER.span(name, cat, **args)
+def span(name: str, cat: str = "engine", **args) -> Span:
+    """A span on the profiler's host line (always) and in the Chrome
+    buffer (when enabled). `cat` names the Chrome event's category."""
+    return Span(name, cat, args)
 
 
 def instant(name: str, cat: str = "engine", **args) -> None:
     TRACER.instant(name, cat, **args)
-
-
-def counter_series(name: str, cat: str = "engine", **values) -> None:
-    TRACER.counter_series(name, cat, **values)
 
 
 def export() -> Dict[str, Any]:

@@ -38,6 +38,7 @@ from lighthouse_tpu.crypto.bls import api as _api
 from lighthouse_tpu.crypto.bls import curves as _oc
 from lighthouse_tpu.crypto.bls.constants import P as _P
 from lighthouse_tpu.crypto.bls.constants import RAND_BITS as _RAND_BITS
+from lighthouse_tpu.observability import trace
 
 from . import curves as cv
 from . import h2c
@@ -71,18 +72,6 @@ def _warm_dispatch(stage_id: str, fallback):
     except ImportError:
         return fallback
     return aot.stage_dispatch("major", stage_id, fallback)
-
-
-def _traced(stage: str, fn, **static_args):
-    """Observability stage wrapper (observability/stages.traced): a
-    no-op attribute check unless tracing/stage-timing is active, in
-    which case the stage blocks until ready and reports its true wall
-    time. Tolerates only a missing module, as _warm_dispatch does."""
-    try:
-        from lighthouse_tpu.observability import stages as _obs_stages
-    except ImportError:
-        return fn
-    return _obs_stages.traced("major", stage, fn, **static_args)
 
 
 # ---------------------------------------------------------------------------
@@ -172,16 +161,10 @@ def _jitted_core(n_bucket: int, k_bucket: int, sharded: bool,
                  n_devices: Optional[int] = None):
     """Three-stage pipeline, each stage its own jit (own cache entry).
     `n_devices` bounds the sharded mesh (default: all devices)."""
-    shape_args = dict(n=n_bucket, k=k_bucket, sharded=sharded)
     if not sharded:
-        stage1 = _traced("h2g2", _warm_dispatch("h2g2", jax.jit(_h2g2_gather)),
-                         **shape_args)
-        stage2 = _traced("prepare",
-                         _warm_dispatch("prepare", jax.jit(_prepare_pairs)),
-                         **shape_args)
-        stage3 = _traced("pairing",
-                         _warm_dispatch("pairing", jax.jit(_pairing_check)),
-                         **shape_args)
+        stage1 = _warm_dispatch("h2g2", jax.jit(_h2g2_gather))
+        stage2 = _warm_dispatch("prepare", jax.jit(_prepare_pairs))
+        stage3 = _warm_dispatch("pairing", jax.jit(_pairing_check))
 
         def core(u, inv_idx, pk_proj, sig_proj, sig_checked, set_mask,
                  scalars):
@@ -217,11 +200,10 @@ def _jitted_core(n_bucket: int, k_bucket: int, sharded: bool,
                 return fn(*args)
         return wrapped
 
-    stage1 = _traced("h2g2", jax.jit(constrained(_h2g2_gather)), **shape_args)
-    stage2 = _traced("prepare", jax.jit(constrained(_prepare_pairs)),
-                     **shape_args)
+    stage1 = jax.jit(constrained(_h2g2_gather))
+    stage2 = jax.jit(constrained(_prepare_pairs))
     # (n+1): leave layout to XLA
-    stage3 = _traced("pairing", jax.jit(unfused(_pairing_check)), **shape_args)
+    stage3 = jax.jit(unfused(_pairing_check))
 
     def core(u, inv_idx, pk_proj, sig_proj, sig_checked, set_mask, scalars):
         h_proj = stage1(u, inv_idx)
@@ -259,7 +241,30 @@ def verify_signature_sets_tpu(
     (api.verify_signature_sets_oracle): empty batch, empty signing_keys,
     infinity signature.
     """
-    return bool(_verify_tpu_impl(sets, sharded))
+    return _verdict(_verify_tpu_impl(sets, sharded))
+
+
+def _verdict(out) -> bool:
+    """The host's read of a verdict; a device verdict is waited for in a
+    `bls.device_wait` span (host early-outs and native answers are
+    already Python bools)."""
+    if isinstance(out, bool):
+        return out
+    with trace.span("bls.device_wait", cat="bls"):
+        return bool(out)
+
+
+@lru_cache(maxsize=None)
+def batches_total():
+    """`bls_batches_total{route}`: the batches `_verify_tpu_impl` answered
+    by host reject (`host_reject`), by the native verifier (`native`) or
+    on the device engine (`device`)."""
+    from lighthouse_tpu.common import metrics as m
+
+    return m.REGISTRY.counter_vec(
+        "bls_batches_total",
+        "TPU-backend BLS batches, by the route that answered them "
+        "(host_reject|native|device)", "route")
 
 
 def cpu_fallback_max() -> int:
@@ -299,18 +304,22 @@ def _buckets(sets, sharded, floors=(1, 1, 1)):
 
 def _verify_tpu_impl(sets, sharded, floors=(1, 1, 1)):
     sets = list(sets)
-    if not sets:
-        return False
-    if any(_host_rejects(s) for s in sets):
+    if not sets or any(_host_rejects(s) for s in sets):
+        batches_total().labels("host_reject").inc()
         return False
 
     if len(sets) <= cpu_fallback_max():
         try:
             from lighthouse_tpu.crypto.bls import cpu_backend
-            return cpu_backend.verify_signature_sets_cpu(sets)
+            with trace.span("bls.native", cat="bls", n=len(sets)):
+                ok = cpu_backend.verify_signature_sets_cpu(sets)
         except Exception:
             pass  # no native toolchain: stay on the device path
+        else:
+            batches_total().labels("native").inc()
+            return ok
 
+    batches_total().labels("device").inc()
     n = len(sets)
     sharded, n_devices, n_bucket, k_bucket, m_floor = _buckets(
         sets, sharded, floors)
@@ -324,63 +333,95 @@ def _verify_tpu_impl(sets, sharded, floors=(1, 1, 1)):
         return _verify_bm_impl(sets, n, n_bucket, k_bucket, m_floor,
                                n_devices)
 
-    # --- stage tensors (host ints -> device limbs) ------------------------
-    # Hash-cons identical messages BEFORE the host SHA and the device h2c
-    # map: a committee's unaggregated attestations share AttestationData,
-    # so both the host hash_to_field and the device SSWU/cofactor work run
-    # once per distinct message (round 5, VERDICT #2).
+    args = _stage_major(sets, n, n_bucket, k_bucket, m_floor)
+    core = _jitted_core(n_bucket, k_bucket, bool(sharded))
+    # Returned WITHOUT bool(): async dispatch — callers that need the
+    # answer now read it (verify_signature_sets_tpu's _verdict);
+    # pipelining callers keep staging the next batch first.
+    with trace.span("bls.dispatch", cat="bls", n_bucket=n_bucket):
+        return core(*args)
+
+
+def _hash_cons(sets, n_bucket):
+    """Distinct messages in first-seen order, and the (n_bucket,) map
+    set -> distinct row. Hash-consing BEFORE the host SHA and the device
+    h2c map: a committee's unaggregated attestations share
+    AttestationData, so both the host hash_to_field and the device
+    SSWU/cofactor work run once per distinct message (round 5, VERDICT
+    #2)."""
     uniq: dict = {}
     inv_idx = np.zeros((n_bucket,), dtype=np.int32)
     for i, s in enumerate(sets):
         inv_idx[i] = uniq.setdefault(bytes(s.message), len(uniq))
-    # Quantized m bucket (same menu as the BM path): stage 1's jit is
-    # shaped by m, so an unquantized next-pow2 would recompile per
-    # committee count here too. Padding rows map through h2c but are
-    # never gathered (inv_idx only points at real rows). The sharded
-    # floor keeps every shard non-empty.
-    m_bucket = max(_m_bucket_for(n_bucket, len(uniq)), m_floor)
-    u = np.zeros((m_bucket, 2, 2, lb.L), dtype=lb.NP_DTYPE)
-    u_real = h2c.hash_to_field_device(list(uniq.keys()))
-    u[: len(uniq)] = np.asarray(u_real)
+    return list(uniq), inv_idx
 
+
+def _padded_pubkeys(sets, n, n_bucket, k_bucket) -> list:
+    """Every set's keys padded to k_bucket, then whole padding sets, with
+    None (infinity) in the padding; flat, set-major."""
     pk_pts = []
     for s in sets:
         pts = [pk.point for pk in s.signing_keys]
         pts += [None] * (k_bucket - len(pts))
         pk_pts.extend(pts)
     pk_pts += [None] * ((n_bucket - n) * k_bucket)
-    pk_proj = cv.g1_from_affine(pk_pts).reshape(n_bucket, k_bucket, 3, lb.L)
+    return pk_pts
 
-    sig_pts = [s.signature.point for s in sets] + [None] * (n_bucket - n)
-    sig_proj = cv.g2_from_affine(sig_pts)
 
+def _masks(sets, n, n_bucket):
+    """(sig_checked, set_mask) at n_bucket; padding sets skip the device
+    subgroup check and are masked out of the pairing."""
     sig_checked = np.zeros((n_bucket,), dtype=bool)
     sig_checked[:n] = [s.signature.subgroup_checked for s in sets]
     sig_checked[n:] = True  # padding: skip the device check
 
     set_mask = np.zeros((n_bucket,), dtype=bool)
     set_mask[:n] = True
+    return sig_checked, set_mask
 
+
+def _draw_scalars(n, n_bucket):
+    """Nonzero RAND_BITS-bit batch coefficients from the host CSPRNG for
+    the n real sets; padding sets carry 1."""
     scalars = np.ones((n_bucket,), dtype=np.uint64)
     for i in range(n):
         r = 0
         while r == 0:
             r = secrets.randbits(_RAND_BITS)
         scalars[i] = r
+    return scalars
 
-    core = _jitted_core(n_bucket, k_bucket, bool(sharded))
-    # Returned WITHOUT bool(): async dispatch — callers that need the
-    # answer now take bool() (verify_signature_sets_tpu); pipelining
-    # callers keep staging the next batch first.
-    return core(
-        jnp.asarray(u),
-        jnp.asarray(inv_idx),
-        pk_proj,
-        sig_proj,
-        jnp.asarray(sig_checked),
-        jnp.asarray(set_mask),
-        jnp.asarray(scalars),
-    )
+
+def _stage_major(sets, n, n_bucket, k_bucket, m_floor):
+    """Stage a batch into the batch-major core's argument tuple, in a
+    `bls.stage` span with the same nested phases as stage_bm."""
+    with trace.span("bls.stage", cat="bls", n=n, n_bucket=n_bucket):
+        with trace.span("bls.stage.h2f", cat="bls"):
+            msgs, inv_idx = _hash_cons(sets, n_bucket)
+            # Quantized m bucket (same menu as the BM path): stage 1's jit
+            # is shaped by m, so an unquantized next-pow2 would recompile
+            # per committee count here too. Padding rows map through h2c
+            # but are never gathered (inv_idx only points at real rows).
+            # The sharded floor keeps every shard non-empty.
+            m_bucket = max(_m_bucket_for(n_bucket, len(msgs)), m_floor)
+            u = np.zeros((m_bucket, 2, 2, lb.L), dtype=lb.NP_DTYPE)
+            u[: len(msgs)] = np.asarray(h2c.hash_to_field_device(msgs))
+
+        with trace.span("bls.stage.points", cat="bls"):
+            pk_proj = cv.g1_from_affine(
+                _padded_pubkeys(sets, n, n_bucket, k_bucket)
+            ).reshape(n_bucket, k_bucket, 3, lb.L)
+            sig_proj = cv.g2_from_affine(
+                [s.signature.point for s in sets] + [None] * (n_bucket - n))
+
+        with trace.span("bls.stage.scalars", cat="bls"):
+            sig_checked, set_mask = _masks(sets, n, n_bucket)
+            scalars = _draw_scalars(n, n_bucket)
+
+        with trace.span("bls.stage.transfer", cat="bls"):
+            return (jnp.asarray(u), jnp.asarray(inv_idx), pk_proj, sig_proj,
+                    jnp.asarray(sig_checked), jnp.asarray(set_mask),
+                    jnp.asarray(scalars))
 
 
 def _layout() -> str:
@@ -463,59 +504,37 @@ def stage_bm(sets, n, n_bucket, k_bucket, scalars=None, m_floor: int = 1):
     from .bm import curves as bmc
     from .bm import h2c as bmh
 
-    uniq: dict = {}
-    inv_idx = np.zeros((n_bucket,), dtype=np.int32)
-    for i, s in enumerate(sets):
-        inv_idx[i] = uniq.setdefault(bytes(s.message), len(uniq))
-    m_bucket = max(
-        _m_bucket_for(n_bucket, len(uniq)), _next_pow2(max(1, m_floor))
-    )
-    u = np.zeros((2, 2, lb.L, m_bucket), dtype=lb.NP_DTYPE)
-    u[..., : len(uniq)] = bmh.hash_to_field_bm_np(list(uniq.keys()))
-    row_mask = np.zeros((m_bucket,), dtype=bool)
-    row_mask[: len(uniq)] = True
+    with trace.span("bls.stage", cat="bls", n=n, n_bucket=n_bucket):
+        with trace.span("bls.stage.h2f", cat="bls"):
+            msgs, inv_idx = _hash_cons(sets, n_bucket)
+            m_bucket = max(
+                _m_bucket_for(n_bucket, len(msgs)), _next_pow2(max(1, m_floor))
+            )
+            u = np.zeros((2, 2, lb.L, m_bucket), dtype=lb.NP_DTYPE)
+            u[..., : len(msgs)] = bmh.hash_to_field_bm_np(msgs)
+            row_mask = np.zeros((m_bucket,), dtype=bool)
+            row_mask[: len(msgs)] = True
 
-    pk_pts = []
-    for s in sets:
-        pts = [pk.point for pk in s.signing_keys]
-        pts += [None] * (k_bucket - len(pts))
-        pk_pts.extend(pts)
-    pk_pts += [None] * ((n_bucket - n) * k_bucket)
-    # Flat minor order is (set, slot) with slot fastest: split the minor
-    # axis and move the slot axis to the front -> (K, 3, L, n).
-    pk_flat = bmc.g1_from_affine_np(pk_pts)              # (3, L, n*K)
-    pk_proj = np.ascontiguousarray(np.moveaxis(
-        pk_flat.reshape(3, lb.L, n_bucket, k_bucket), -1, 0
-    ))
+        with trace.span("bls.stage.points", cat="bls"):
+            # Flat minor order is (set, slot) with slot fastest: split the
+            # minor axis and move the slot axis to the front -> (K, 3, L, n).
+            pk_flat = bmc.g1_from_affine_np(
+                _padded_pubkeys(sets, n, n_bucket, k_bucket))  # (3, L, n*K)
+            pk_proj = np.ascontiguousarray(np.moveaxis(
+                pk_flat.reshape(3, lb.L, n_bucket, k_bucket), -1, 0
+            ))
+            sig_proj = bmc.g2_from_affine_np(
+                [s.signature.point for s in sets] + [None] * (n_bucket - n))
 
-    sig_pts = [s.signature.point for s in sets] + [None] * (n_bucket - n)
-    sig_proj = bmc.g2_from_affine_np(sig_pts)
+        with trace.span("bls.stage.scalars", cat="bls"):
+            sig_checked, set_mask = _masks(sets, n, n_bucket)
+            if scalars is None:
+                scalars = _draw_scalars(n, n_bucket)
 
-    sig_checked = np.zeros((n_bucket,), dtype=bool)
-    sig_checked[:n] = [s.signature.subgroup_checked for s in sets]
-    sig_checked[n:] = True
-
-    set_mask = np.zeros((n_bucket,), dtype=bool)
-    set_mask[:n] = True
-
-    if scalars is None:
-        scalars = np.ones((n_bucket,), dtype=np.uint64)
-        for i in range(n):
-            r = 0
-            while r == 0:
-                r = secrets.randbits(_RAND_BITS)
-            scalars[i] = r
-
-    args = (
-        jnp.asarray(u),
-        jnp.asarray(inv_idx),
-        jnp.asarray(row_mask),
-        jnp.asarray(pk_proj),
-        jnp.asarray(sig_proj),
-        jnp.asarray(sig_checked),
-        jnp.asarray(set_mask),
-        jnp.asarray(scalars),
-    )
+        with trace.span("bls.stage.transfer", cat="bls"):
+            args = tuple(jnp.asarray(a) for a in (
+                u, inv_idx, row_mask, pk_proj, sig_proj, sig_checked,
+                set_mask, scalars))
     return args, m_bucket
 
 
@@ -536,7 +555,8 @@ def _verify_bm_impl(sets, n, n_bucket, k_bucket, m_floor: int,
         args = tuple(pm.shard_batch_minor(a, mesh) for a in args)
     core = bmb.jitted_core(n_bucket, k_bucket, m_bucket, sharded=sharded,
                            n_devices=n_devices)
-    return core(*args)
+    with trace.span("bls.dispatch", cat="bls", n_bucket=n_bucket):
+        return core(*args)
 
 
 def pinned_verifier(sets, sharded: Optional[bool] = None):
@@ -552,7 +572,7 @@ def pinned_verifier(sets, sharded: Optional[bool] = None):
     n_uniq = len({bytes(s.message) for s in good})
     floors = (n_bucket, k_bucket,
               max(_m_bucket_for(n_bucket, n_uniq), m_floor))
-    return lambda sub: bool(_verify_tpu_impl(sub, sharded, floors))
+    return lambda sub: _verdict(_verify_tpu_impl(sub, sharded, floors))
 
 
 # Register with the API seam (mirrors define_mod! backend instantiation,

@@ -264,16 +264,6 @@ def _warm_dispatch(stage_id: str, fallback):
     return aot.stage_dispatch("bm", stage_id, fallback)
 
 
-def _traced(stage: str, fn, **static_args):
-    """Observability stage wrapper (see ops/backend._traced), engine
-    label "bm"."""
-    try:
-        from lighthouse_tpu.observability import stages as _obs_stages
-    except ImportError:
-        return fn
-    return _obs_stages.traced("bm", stage, fn, **static_args)
-
-
 def jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
                 prep_chunk: Optional[int] = None, sharded: bool = False,
                 n_devices: Optional[int] = None):
@@ -298,8 +288,6 @@ def jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
 def _jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
                  prep_chunk: int, sharded: bool,
                  n_devices: Optional[int]):
-    shape_args = dict(n=n_bucket, k=k_bucket, m=m_bucket,
-                      chunk=prep_chunk, sharded=sharded)
     del n_bucket, k_bucket  # cache keys; shapes live in the arguments
     if not sharded:
         stage1 = _warm_dispatch("h2g2", _stage1_jit)
@@ -331,11 +319,6 @@ def _jitted_core(n_bucket: int, k_bucket: int, m_bucket: int,
         stage2 = jax.jit(constrained(_make_prepare(m_bucket, prep_chunk)))
         stage3 = jax.jit(_miller_product)
         stage4 = jax.jit(_final_check)
-
-    stage1 = _traced("h2g2", stage1, **shape_args)
-    stage2 = _traced("prepare", stage2, **shape_args)
-    stage3 = _traced("pairing", stage3, **shape_args)
-    stage4 = _traced("final_exp", stage4, **shape_args)
 
     def core(u, inv_idx, row_mask, pk_proj, sig_proj, sig_checked,
              set_mask, scalars):

@@ -12,30 +12,23 @@ import pathlib
 import subprocess
 import sys
 
-import pytest
-
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 import chip_smoke  # noqa: E402
 
 
-@pytest.fixture()
-def stage_timing():
-    from lighthouse_tpu.observability import stages
-
-    stages.force_timing(True)
-    yield
-    stages.force_timing(False)
-
-
-def test_phases_b_and_c_rehearsal(stage_timing):
+def test_phases_b_and_c_rehearsal():
     prepared = [chip_smoke.slot_attestations(4000, 2) for _ in range(2)]
     assert len(prepared[0][1]) == 8
     assert chip_smoke.planned_batches(prepared[0][1]) == [(8, 4)]
+    device0 = chip_smoke._device_batches()
     b = chip_smoke.phase_b(prepared, n_poison=1)
     assert b["valid"]["imported"] == 8 and b["valid"]["batch_sizes"] == [8]
     assert b["poisoned"]["rejected"] == b["poisoned"]["poisoned"] == [4]
+    # bls_batches_total{route="device"}: the valid batch, then the
+    # poisoned one and its bisection (8, 4, 4, 2, 1, 1, 2 sets).
+    assert chip_smoke._device_batches() - device0 == 1 + 7
 
     c = chip_smoke.phase_c(chip_smoke.distinct_sets(8, 1))
     assert c["valid"] == {**c["valid"], "device": True, "native": True}

@@ -1,12 +1,10 @@
 """Observability spine: Prometheus round-trip, Chrome trace schema,
-stage timers, batch-lifecycle instrumentation, compile-event accounting,
-probe-report envelope (ISSUE 13 tentpole + satellites)."""
+batch-lifecycle instrumentation, compile-event accounting, probe-report
+envelope."""
 
 import json
-import threading
 import urllib.request
 
-import numpy as np
 import pytest
 
 
@@ -183,13 +181,12 @@ def test_trace_export_valid_chrome_schema(tracer):
         with tracer.span("inner2"):
             pass
     tracer.instant("mark", cat="compile", detail=1)
-    tracer.counter_series("depths", q=3)
 
     doc = json.loads(json.dumps(tracer.export()))   # JSON round-trip
     assert isinstance(doc["traceEvents"], list)
     assert doc["otherData"]["dropped_events"] == 0
     phases = sorted(e["ph"] for e in doc["traceEvents"])
-    assert phases == ["C", "X", "X", "X", "i"]
+    assert phases == ["X", "X", "X", "i"]
     for ev in doc["traceEvents"]:
         assert isinstance(ev["name"], str) and ev["name"]
         assert isinstance(ev["ts"], (int, float)) and ev["ts"] >= 0
@@ -231,7 +228,6 @@ def test_trace_disabled_records_nothing_and_passes_through():
     with t.span("x") as handle:
         assert handle is None
     t.instant("y")
-    t.counter_series("z", v=1)
     assert t.export()["traceEvents"] == []
 
 
@@ -257,46 +253,12 @@ def test_trace_buffer_cap_counts_drops():
 
 
 # ---------------------------------------------------------------------------
-# Stage timers (tentpole: engine seams)
+# Engine cores
 # ---------------------------------------------------------------------------
 
 
-def test_traced_stage_noop_when_disabled():
-    from lighthouse_tpu.observability import stages, trace
-
-    assert not trace.TRACER.enabled
-    calls = []
-
-    def fn(x):
-        calls.append(x)
-        return np.ones(2)
-
-    wrapped = stages.traced("major", "h2g2", fn, n=4)
-    out = wrapped(7)
-    assert calls == [7] and out.shape == (2,)
-    assert wrapped.__wrapped__ is fn
-
-
-def test_traced_stage_records_span_and_histogram(global_trace):
-    from lighthouse_tpu.common import metrics as m
-    from lighthouse_tpu.observability import stages
-
-    hist = stages.stage_seconds(m.REGISTRY)
-    before = hist.get_count(engine="bm", stage="pairing")
-    wrapped = stages.traced("bm", "pairing",
-                            lambda a, b: (np.zeros(3), np.ones(1)), n=8, m=8)
-    out = wrapped(1, 2)
-    assert isinstance(out, tuple)
-    assert hist.get_count(engine="bm", stage="pairing") == before + 1
-    spans = [e for e in global_trace.events()
-             if e["ph"] == "X" and e["cat"] == "stage"]
-    assert any(e["name"] == "bm:pairing" and e["args"]["n"] == 8
-               for e in spans)
-
-
 def test_engine_cores_expose_traced_stages():
-    """Both engine builders surface `core.stages`; the wrappers must
-    pass through to the real stage callables (builders only — no
+    """Both engine builders surface `core.stages` (builders only — no
     execution, so no compile cost in tier-1)."""
     from lighthouse_tpu.ops import backend as be
     from lighthouse_tpu.ops.bm import backend as bmb
@@ -526,64 +488,3 @@ def test_probe_report_env_facts_present():
     rep = report.make("probe_env")
     assert rep["env"].get("jax_platform") == "cpu"
     assert rep["env"].get("device_count", 0) >= 1
-
-
-# ---------------------------------------------------------------------------
-# Roofline script (tentpole deliverable; FLOP model only — the full
-# table runs in scripts/report_roofline.py outside tier-1 time budgets)
-# ---------------------------------------------------------------------------
-
-
-def test_roofline_flop_model_matches_notes():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "report_roofline",
-        os.path.join(os.path.dirname(__file__), os.pardir, "scripts",
-                     "report_roofline.py"))
-    rr = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rr)
-    per_set = (rr.FLOPS_H2C_PER_MSG + rr.FLOPS_PREP_PER_SET
-               + rr.FLOPS_PAIRING_PER_PAIR)
-    assert per_set == pytest.approx(1.7e9)     # NOTES_TPU_PERF model
-    # 200k all-distinct sigs/s -> ~340 TFLOP/s > 197 bf16 peak.
-    assert 200_000 * per_set / 1e12 == pytest.approx(340, rel=0.01)
-    # Stage attribution: h2c rides DISTINCT messages, prep rides sets.
-    assert rr._stage_flops("h2g2", 1024, 16) == 16 * rr.FLOPS_H2C_PER_MSG
-    assert rr._stage_flops("prepare", 1024, 16) == 1024 * rr.FLOPS_PREP_PER_SET
-    assert rr._stage_flops("pairing", 1024, 16) == 17 * rr.FLOPS_PAIRING_PER_PAIR
-
-
-def test_roofline_table_from_synthetic_trace(tmp_path, capsys):
-    """--from-trace renders the per-stage table from a saved Chrome
-    trace without touching the engines."""
-    import importlib.util
-    import os
-
-    trace_doc = {"traceEvents": [
-        {"name": f"bm:{stage}", "cat": "stage", "ph": "X", "ts": 0.0,
-         "dur": dur_us, "pid": 1, "tid": 1,
-         "args": {"engine": "bm", "stage": stage, "n": 1024, "depth": 1}}
-        for stage, dur_us in (("h2g2", 30_000.0), ("prepare", 50_000.0),
-                              ("pairing", 20_000.0))
-    ]}
-    path = tmp_path / "synthetic.trace.json"
-    path.write_text(json.dumps(trace_doc))
-
-    spec = importlib.util.spec_from_file_location(
-        "report_roofline",
-        os.path.join(os.path.dirname(__file__), os.pardir, "scripts",
-                     "report_roofline.py"))
-    rr = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rr)
-    assert rr.main(["--from-trace", str(path),
-                    "--device-kind", "TPU v5 lite"]) == 0
-    out = capsys.readouterr().out
-    assert "h2c" in out and "prep(+combine)" in out and "pairing" in out
-    assert "roofline:" in out
-    # 1024 sets / 0.1s total = 10240 sigs/s in the TOTAL row.
-    assert "10,240" in out
-    # No default peak: a kind outside the published table is an error.
-    with pytest.raises(SystemExit):
-        rr.main(["--from-trace", str(path), "--device-kind", "cpu"])
