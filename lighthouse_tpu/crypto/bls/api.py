@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import secrets
 from dataclasses import dataclass
+from functools import lru_cache
 from typing import Iterable, List, Optional, Sequence
 
 from lighthouse_tpu.observability import trace
@@ -36,6 +37,8 @@ from . import fields as f
 from . import hash_to_curve as h2c
 from . import pairing as pr
 from .constants import (
+    FLAG_COMPRESSED,
+    FLAG_INFINITY,
     PUBLIC_KEY_BYTES_LEN,
     R,
     RAND_BITS,
@@ -153,12 +156,16 @@ class Signature:
 
     @classmethod
     def from_bytes(cls, data: bytes, subgroup_check: bool = True) -> "Signature":
+        native = _native_g2()
         try:
-            pt = c.g2_from_compressed(data)
+            pt = _g2_decompress(data, native)
         except ValueError as e:
             raise BlsError(str(e))   # malformed wire bytes (see PublicKey)
-        if subgroup_check and pt is not None and not c.g2_in_subgroup(pt):
-            raise BlsError("signature not in G2 subgroup")
+        if subgroup_check and pt is not None:
+            ok = (native.g2_in_subgroup_native(pt) if native
+                  else c.g2_in_subgroup(pt))
+            if not ok:
+                raise BlsError("signature not in G2 subgroup")
         return cls(point=pt, subgroup_checked=subgroup_check)
 
     def to_bytes(self) -> bytes:
@@ -170,6 +177,43 @@ class Signature:
     @classmethod
     def infinity(cls) -> "Signature":
         return cls(point=None)
+
+
+@lru_cache(maxsize=None)
+def _native_g2():
+    """The native library's G2 decoder (cpu_backend), or None where the
+    library cannot be built (no toolchain): the oracle decodes then."""
+    try:
+        from . import cpu_backend
+        cpu_backend.get_lib()
+    except Exception:
+        return None
+    return cpu_backend
+
+
+@lru_cache(maxsize=None)
+def signature_decodes_total():
+    """`bls_signature_decodes_total{route}`: G2 signature decodes by the
+    path that took the square root: the native library (`native`) or the
+    pure-Python oracle (`python`; also every encoding the oracle answers
+    from its length or flags alone, infinity included)."""
+    from lighthouse_tpu.common import metrics as m
+
+    return m.REGISTRY.counter_vec(
+        "bls_signature_decodes_total",
+        "G2 signature decodes, by the route that decoded them "
+        "(native|python)", "route")
+
+
+def _g2_decompress(data: bytes, native):
+    """curves.g2_from_compressed, with the square root on the native
+    library where it loads. The same point, or the same ValueError."""
+    if (native is None or len(data) != SIGNATURE_BYTES_LEN
+            or not data[0] & FLAG_COMPRESSED or data[0] & FLAG_INFINITY):
+        signature_decodes_total().labels("python").inc()
+        return c.g2_from_compressed(data)
+    signature_decodes_total().labels("native").inc()
+    return native.g2_decompress_native(data)
 
 
 @dataclass(frozen=True)

@@ -38,6 +38,11 @@ def get_lib():
         lib.blscpu_verify_batch.restype = ctypes.c_int
         lib.blscpu_hash_to_g2.restype = ctypes.c_int
         lib.blscpu_g2_in_subgroup.restype = ctypes.c_int
+        lib.blscpu_g2_in_subgroup.argtypes = [ctypes.c_char_p,
+                                              ctypes.c_uint8]
+        lib.blscpu_g2_decompress.restype = ctypes.c_int
+        lib.blscpu_g2_decompress.argtypes = [ctypes.c_char_p,
+                                             ctypes.POINTER(ctypes.c_uint8)]
         _lib = lib
     return _lib
 
@@ -104,6 +109,13 @@ def verify_signature_sets_cpu(sets: Sequence["api.SignatureSet"]) -> bool:
     return res == 1
 
 
+def _dec_g2(b: bytes):
+    return (
+        (int.from_bytes(b[0:48], "big"), int.from_bytes(b[48:96], "big")),
+        (int.from_bytes(b[96:144], "big"), int.from_bytes(b[144:192], "big")),
+    )
+
+
 def hash_to_g2_native(msg: bytes):
     """Native hash_to_curve (KAT/differential surface)."""
     lib = get_lib()
@@ -111,11 +123,33 @@ def hash_to_g2_native(msg: bytes):
     r = lib.blscpu_hash_to_g2(msg, len(msg), out)
     if r == 0:
         return None
-    b = bytes(out)
-    return (
-        (int.from_bytes(b[0:48], "big"), int.from_bytes(b[48:96], "big")),
-        (int.from_bytes(b[96:144], "big"), int.from_bytes(b[144:192], "big")),
-    )
+    return _dec_g2(bytes(out))
+
+
+# blscpu_g2_decompress's error codes, as the oracle's
+# curves.g2_from_compressed words the same rejection.
+_DECOMPRESS_ERRORS = {-1: "G2 x out of range", -2: "G2 x not on curve"}
+
+
+def g2_decompress_native(data: bytes):
+    """Decompress a 96-byte G2 encoding that carries the compression flag
+    and not the infinity flag (the caller checks length and flags, as the
+    oracle's curves.g2_from_compressed does). No subgroup check. Raises
+    ValueError with the oracle's text for x >= p and x off the curve."""
+    data = bytes(data)
+    if len(data) != 96:
+        raise ValueError("bad G2 length")
+    out = (ctypes.c_uint8 * 192)()
+    r = get_lib().blscpu_g2_decompress(data, out)
+    if r != 1:
+        raise ValueError(_DECOMPRESS_ERRORS[r])
+    return _dec_g2(bytes(out))
+
+
+def g2_in_subgroup_native(pt) -> bool:
+    """G2 subgroup check of an affine point on the curve; the same boolean
+    as curves.g2_in_subgroup."""
+    return get_lib().blscpu_g2_in_subgroup(_enc_g2(pt), 0) == 1
 
 
 api.register_backend("cpu", verify_signature_sets_cpu)

@@ -442,10 +442,11 @@ static bool fp2_is_lex_largest(const fp2& y) {
 // s = sqrt(a0^2 + a1^2) (the norm is a square when a is), d = (a0+s)/2
 // or (a0-s)/2 (whichever is a square; 4d^2 - a1^2 = 4 a0 d), then
 // sqrt(a) = x0 + (a1 / 2x0) u with x0 = sqrt(d). Much cheaper than the
-// oracle's 762-bit Tonelli–Shanks (three ~381-bit Fp pows instead of a
-// 762-bit Fp2 pow) and verified against it by construction: we check
-// r^2 == a before returning.
+// oracle's 762-bit Tonelli–Shanks (two or three ~381-bit Fp pows and no
+// inversion, instead of 762-bit Fp2 pows) and verified against it by
+// construction: we check r^2 == a before returning.
 static uint8_t P_PLUS_1_OVER_4_BE[48];
+static uint8_t P_MINUS_3_OVER_4_BE[48];
 
 static bool fp_sqrt(const fp& a, fp* out) {
   fp c = fp_pow_be(a, P_PLUS_1_OVER_4_BE, 48);
@@ -476,13 +477,18 @@ static bool fp2_sqrt(const fp2& a, fp2* out) {
   fp norm = fp_add(fp_sqr(a.c0), fp_sqr(a.c1));
   fp s;
   if (!fp_sqrt(norm, &s)) return false;  // norm non-square: a non-square
+  // x0 = d^((p+1)/4) = c*d with c = d^((p-3)/4); when x0^2 == d (d is
+  // nonzero here, as a1 is), c*x0 = d^((p-1)/2) = 1: c is 1/x0, no inverse.
   fp d = fp_mul(fp_add(a.c0, s), FP_HALF);
-  fp x0;
-  if (!fp_sqrt(d, &x0)) {
+  fp c = fp_pow_be(d, P_MINUS_3_OVER_4_BE, 48);
+  fp x0 = fp_mul(c, d);
+  if (!fp_eq(fp_sqr(x0), d)) {
     d = fp_mul(fp_sub(a.c0, s), FP_HALF);
-    if (!fp_sqrt(d, &x0)) return false;
+    c = fp_pow_be(d, P_MINUS_3_OVER_4_BE, 48);
+    x0 = fp_mul(c, d);
+    if (!fp_eq(fp_sqr(x0), d)) return false;
   }
-  fp x1 = fp_mul(a.c1, fp_inv(fp_mul_small(x0, 2)));
+  fp x1 = fp_mul(fp_mul(a.c1, c), FP_HALF);
   fp2 r = {x0, x1};
   if (!eq(sqr(r), a)) return false;
   *out = r;
@@ -878,6 +884,8 @@ extern "C" int blscpu_init() {
         P_MINUS_2_BE[47 - (8 * i + j)] = uint8_t(pm2[i] >> (8 * j));
         P_MINUS_1_OVER_2_BE[47 - (8 * i + j)] = uint8_t(ph[i] >> (8 * j));
         P_PLUS_1_OVER_4_BE[47 - (8 * i + j)] = uint8_t(pq[i] >> (8 * j));
+        P_MINUS_3_OVER_4_BE[47 - (8 * i + j)] =
+            uint8_t((pq[i] - (i == 0)) >> (8 * j));  // low limb odd
       }
   }
   compute_e_exp();
@@ -1399,7 +1407,28 @@ extern "C" int blscpu_hash_to_g2(const uint8_t* msg, uint32_t msg_len,
   return 1;
 }
 
-// G2 subgroup check on an affine point (for parity tests).
+// G2 decompression, ZCash layout: in96 = x1 (flag bits in the top three
+// of byte 0) then x0, big-endian. Flags, length and infinity are the
+// caller's; no subgroup check. out192 = affine (X0,X1,Y0,Y1) big-endian.
+// Returns 1, -1 when x0 or x1 >= p, -2 when x is not on the curve.
+extern "C" int blscpu_g2_decompress(const uint8_t* in96, uint8_t* out192) {
+  blscpu_init();
+  uint8_t x1_be[48];
+  memcpy(x1_be, in96, 48);
+  x1_be[0] &= 0x1F;
+  fp2 x;
+  if (!fp_from_be(x1_be, &x.c1) || !fp_from_be(in96 + 48, &x.c0)) return -1;
+  fp2 y;
+  if (!fp2_sqrt(add(mul(sqr(x), x), B2_COEFF), &y)) return -2;
+  if (fp2_is_lex_largest(y) != bool(in96[0] & 0x20)) y = neg(y);
+  fp_to_be(x.c0, out192);
+  fp_to_be(x.c1, out192 + 48);
+  fp_to_be(y.c0, out192 + 96);
+  fp_to_be(y.c1, out192 + 144);
+  return 1;
+}
+
+// G2 subgroup check on an affine point (Signature.from_bytes, parity tests).
 extern "C" int blscpu_g2_in_subgroup(const uint8_t* pt192, uint8_t inf) {
   blscpu_init();
   jac<fp2> q;

@@ -159,6 +159,30 @@ def test_batch_verify_with_poison_isolates_culprit(harness):
     assert isinstance(results[3], VerifiedUnaggregatedAttestation)
 
 
+def test_batch_decodes_signatures_on_the_native_library(harness):
+    """Each attestation that passes the gossip checks has its signature
+    decoded once, by the native library; one that fails them (a repeat
+    of a validator's vote) is never decoded."""
+    from lighthouse_tpu.crypto.bls import api
+
+    chain = harness.chain
+    slot = harness.current_slot
+    atts = harness.make_attestations(slot)
+    committee = chain.committees_at(slot).committee(slot, 0)
+    singles = [harness.single_attestation(atts[0], pos, committee)
+               for pos in range(min(3, len(committee)))]
+    harness.advance_slot()
+    decodes = api.signature_decodes_total()
+    before = decodes.get("native"), decodes.get("python")
+    results = batch_verify_unaggregated_attestations(
+        chain, [(a, None) for a in singles + singles[:1]])
+    assert isinstance(results[-1], AttestationError)
+    passed = sum(not isinstance(r, AttestationError) for r in results)
+    assert passed == len(singles)
+    assert decodes.get("native") - before[0] == passed
+    assert decodes.get("python") == before[1]
+
+
 def test_aggregate_verification(harness):
     chain = harness.chain
     harness.extend_chain(2, attest=False)

@@ -4,6 +4,7 @@ vs the pure-Python oracle — the bit-agreement contract of VERDICT r2 #2
 external known-answer vectors in test_known_answers.py, so agreement here
 chains the native path to the same ground truth."""
 
+import functools
 import os
 import secrets
 
@@ -13,7 +14,7 @@ from lighthouse_tpu.crypto.bls import api
 from lighthouse_tpu.crypto.bls import curves as cv
 from lighthouse_tpu.crypto.bls import fields as f
 from lighthouse_tpu.crypto.bls import hash_to_curve as h2c
-from lighthouse_tpu.crypto.bls.constants import R
+from lighthouse_tpu.crypto.bls.constants import P, R
 
 cpu_backend = pytest.importorskip(
     "lighthouse_tpu.crypto.bls.cpu_backend",
@@ -167,3 +168,150 @@ def test_native_build_is_keyed_by_source_flags_and_host(tmp_path,
         'extern "C" int probe_answer() { return 7; }\n')
     monkeypatch.setattr(native, "_cache", {})
     assert native.load("probe").probe_answer() == 7
+
+
+# ---------------------------------------------------------------------------
+# G2 signature decoding: blscpu_g2_decompress behind Signature.from_bytes,
+# against the oracle curves.g2_from_compressed.
+# ---------------------------------------------------------------------------
+
+# benchmark/pool.order13_point(): on E2, of order 13, outside G2. The
+# gossip benchmark's invalid attestations carry a valid signature plus it.
+_ORDER13 = bytes.fromhex(
+    "a792718e185cdb1c6c487ddb1d7aa26201545014e38fa86b90438501ec546be9"
+    "fcc7eaf637367b82cbe47779ff8ea79e104d14ecc0a2abed5c64adf27363c0e2"
+    "73c7e19f5385785e5b6a2b070a2970e64284507acb64bdf40dbcef84f93cb220")
+
+
+@functools.lru_cache(maxsize=None)
+def _sig_point():
+    return _keypair(7).sign(b"\x05" * 32).point
+
+
+def _valid_sigs():
+    """32 G2 points, 16 and their negations: both sign bits."""
+    s, acc, out = _sig_point(), None, []
+    for _ in range(16):
+        acc = cv.g2_add(acc, s)
+        out += [cv.g2_to_compressed(acc), cv.g2_to_compressed(cv.g2_neg(acc))]
+    return out
+
+
+def _x1_zero():
+    """An on-curve x = (x0, 0), both signs."""
+    x0 = 5
+    while f.fp2_sqrt(f.fp2_add(f.fp2_mul(f.fp2_sqr((x0, 0)), (x0, 0)),
+                               (4, 4))) is None:
+        x0 += 1
+    enc = bytearray(96)
+    enc[0] = 0x80
+    enc[48:] = x0.to_bytes(48, "big")
+    return [bytes(enc), bytes([0xA0]) + bytes(enc[1:])]
+
+
+def _with_x(x0=None, x1=None):
+    enc = bytearray(cv.g2_to_compressed(_sig_point()))
+    if x1 is not None:
+        enc[:48] = x1.to_bytes(48, "big")
+        enc[0] |= 0x80
+    if x0 is not None:
+        enc[48:] = x0.to_bytes(48, "big")
+    return bytes(enc)
+
+
+def _off_curve():
+    enc = cv.g2_to_compressed(_sig_point())
+    x0 = int.from_bytes(enc[48:], "big")
+    while True:
+        x0 += 1
+        try:
+            cv.g2_from_compressed(_with_x(x0=x0))
+        except ValueError:
+            return [_with_x(x0=x0)]
+
+
+def _order13():
+    return [_ORDER13, cv.g2_to_compressed(
+        cv.g2_add(_sig_point(), cv.g2_from_compressed(_ORDER13)))]
+
+
+_DECODE_CASES = {
+    "valid-both-signs": _valid_sigs,
+    "x1-zero": _x1_zero,
+    "infinity": lambda: [bytes([0xC0]) + bytes(95)],
+    "infinity-noncanonical": lambda: [bytes([0xE0]) + bytes(95),
+                                      bytes([0xC0]) + bytes(94) + b"\x01"],
+    "x0-ge-p": lambda: [_with_x(x0=P), _with_x(x0=2**381 - 1)],
+    "x1-ge-p": lambda: [_with_x(x1=P), _with_x(x1=2**381 - 1)],
+    "off-curve": _off_curve,
+    "uncompressed": lambda: [bytes([_with_x()[0] & 0x7F]) + _with_x()[1:]],
+    "wrong-length": lambda: [_with_x()[:95], _with_x() + b"\x00"],
+    "order-13": _order13,
+}
+
+
+def _oracle(data: bytes, subgroup_check: bool):
+    """What the pure-Python path answers: a point, or the error text."""
+    try:
+        pt = cv.g2_from_compressed(data)
+    except ValueError as e:
+        return str(e)
+    if subgroup_check and pt is not None and not cv.g2_in_subgroup(pt):
+        return "signature not in G2 subgroup"
+    return pt
+
+
+def _decode(data: bytes, subgroup_check: bool):
+    try:
+        return api.Signature.from_bytes(data, subgroup_check).point
+    except api.BlsError as e:
+        return str(e)
+
+
+def _decodes():
+    return api.signature_decodes_total()
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE_CASES))
+def test_signature_decode_matches_oracle(case):
+    """Every encoding decodes to the oracle's point, or raises BlsError
+    with the oracle's words, with and without the subgroup check; the
+    native library takes every encoding that reaches a square root."""
+    encs = _DECODE_CASES[case]()
+    assert api._native_g2() is cpu_backend
+    before = _decodes().get("native")
+    for data in encs:
+        for check in (False, True):
+            assert _decode(data, check) == _oracle(data, check), (case, check)
+    structural = {"infinity", "infinity-noncanonical", "uncompressed",
+                  "wrong-length"}
+    rooted = 0 if case in structural else 2 * len(encs)
+    assert _decodes().get("native") - before == rooted
+    if case == "order-13":
+        for data in encs:
+            assert isinstance(_decode(data, False), tuple)
+            assert _decode(data, True) == "signature not in G2 subgroup"
+
+
+@pytest.fixture
+def no_native_library(monkeypatch):
+    """The native library fails to load, as on a host with no toolchain."""
+    def fail(name):
+        raise OSError("no toolchain")
+
+    monkeypatch.setattr(cpu_backend, "_lib", None)
+    monkeypatch.setattr(cpu_backend, "load", fail)
+    api._native_g2.cache_clear()
+    yield
+    api._native_g2.cache_clear()
+
+
+def test_signature_decode_falls_back_to_the_oracle(no_native_library):
+    encs = _valid_sigs()[:2] + _order13()
+    want = [cv.g2_from_compressed(d) for d in encs]
+    before = {r: _decodes().get(r) for r in ("native", "python")}
+    assert [api.Signature.from_bytes(d, subgroup_check=False).point
+            for d in encs] == want
+    assert api._native_g2() is None
+    assert _decodes().get("python") - before["python"] == len(encs)
+    assert _decodes().get("native") == before["native"]
